@@ -394,7 +394,15 @@ def run(csv_writer=None, *, smoke: bool = False, repeats: int = 1,
             rows.extend(prows)
 
     by_mode = {row["mode"]: row for row in rows}
-    out = {"rows": rows, "repeats": max(1, repeats)}
+    dev = jax.devices()[0]
+    # every timing here is from the device named in the file; from a CPU run
+    # it is a CPU timing of the reduced preset, never a chip number
+    out = {
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "rows": rows,
+        "repeats": max(1, repeats),
+    }
     if "continuous" in by_mode:
         out["speedup_continuous_vs_serial"] = round(
             by_mode["continuous"]["tokens_per_s"] / by_mode["serial"]["tokens_per_s"], 3

@@ -26,6 +26,8 @@ import csv
 import inspect
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from ._agg import median_rows
 from . import (
     bench_channels,
@@ -89,6 +91,7 @@ def main() -> None:
                     help="add the shared-prompt prefix-cache rows to "
                     "bench_serve (paged with vs without the radix cache)")
     args = ap.parse_args()
+    enable_compile_cache()
     names = args.names or list(ALL)
     all_rows: list[dict] = []
     for name in names:

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.backends import spmd
 from repro.configs import ShapeConfig, get_config
+from repro.launch.mesh import make_mesh
 from repro.models import build
 from repro.models.model_zoo import param_count
 from repro.train import checkpoint as ckpt
@@ -61,7 +62,7 @@ if ckpt.latest_step(args.ckpt_dir) is not None:
     print(f"resumed from checkpoint at step {start_step}")
 
 # HiCR: the train step is an ExecutionUnit on the SPMD compute manager
-mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
 cpm = spmd.SpmdComputeManager(mesh)
 pu = cpm.create_processing_unit(cpm.mesh_compute_resource())
 cpm.initialize(pu)

@@ -13,6 +13,7 @@ exposed as an allocatable memory space.
 """
 from __future__ import annotations
 
+import os
 from typing import Any, Sequence
 
 import jax
@@ -43,7 +44,17 @@ from repro.core.stateless import (
     Topology,
 )
 
-_DEFAULT_DEVMEM = 16 << 30  # assume one v5e-chip's worth when stats missing
+def _memory_bytes(d) -> int:
+    """Bytes a device's memory holds: the accelerator's reported
+    `bytes_limit`, or host RAM for CPU devices (which report no stats)."""
+    stats = d.memory_stats() or {}
+    if "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    if d.platform == "cpu":
+        return os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
+    raise RuntimeError(
+        f"{d.platform} device {d.id} ({d.device_kind}) reports no bytes_limit"
+    )
 
 
 class JaxTopologyManager(TopologyManager):
@@ -53,11 +64,6 @@ class JaxTopologyManager(TopologyManager):
         devices = []
         for d in jax.local_devices():
             dev_id = f"jax-{d.platform}-{d.id}"
-            try:
-                stats = d.memory_stats() or {}
-                size = int(stats.get("bytes_limit", _DEFAULT_DEVMEM))
-            except Exception:  # noqa: BLE001 - CPU devices expose no stats
-                size = _DEFAULT_DEVMEM
             cr = ComputeResource(
                 kind=(
                     ComputeResourceKind.TPU_TENSORCORE.value
@@ -76,7 +82,7 @@ class JaxTopologyManager(TopologyManager):
                 ),
                 index=d.id,
                 device_id=dev_id,
-                size_bytes=size,
+                size_bytes=_memory_bytes(d),
             )
             devices.append(
                 Device(

@@ -63,7 +63,6 @@ class ArchConfig:
     # execution knobs (hillclimbing surface)
     scan_layers: bool = True
     remat_policy: str = "minimal"  # none | minimal | full
-    use_pallas: bool = False  # swap jnp attention for Pallas kernels (TPU)
     # "naive": oracle attention, materializes (Sq, Skv) scores (the
     # paper-faithful baseline kernel). "blocked": flash-style q/kv-chunked
     # online-softmax attention (beyond-paper §Perf optimization).

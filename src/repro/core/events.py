@@ -80,7 +80,9 @@ class Event:
                 return
             self._flag.set()
             callbacks, self._callbacks = self._callbacks, []
-            self._poll = None
+            # the hooks close over what produced the event (an execution
+            # state holds its arguments); a done event keeps none of it
+            self._poll = self._waiter = None
         for cb in callbacks:
             cb(self)
 

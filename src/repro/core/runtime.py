@@ -7,11 +7,12 @@ packages that pattern: callers name a backend (``"hostcpu"``, ``"jaxdev"``,
 no application-level import of concrete backend modules, so the serving and
 launch layers stay backend-agnostic.
 
-A Runtime also owns a default processing unit (first compute resource of the
-queried topology) and offers the execution entry points of the unified
-completion API: ``submit()`` dispatches an execution unit and returns its
-`Future`; ``drive()`` is an event-driven loop multiplexing in-flight
-completion objects (compute futures, transfer events, channel ops);
+A Runtime also owns a default processing unit (the `resource`-th compute
+resource of the queried topology, the first by default) and offers the
+execution entry points of the unified completion API: ``submit()``
+dispatches an execution unit and returns its `Future`; ``drive()`` is an
+event-driven loop multiplexing in-flight completion objects (compute
+futures, transfer events, channel ops);
 ``run()`` is the synchronous shim (``submit(...).result()``). A Runtime is
 a context manager — ``with Runtime(...) as rt:`` finalizes the default
 processing unit on exit, so worker threads are never leaked.
@@ -51,6 +52,11 @@ class Runtime:
         usage, e.g. hostcpu topology + jaxdev compute).
     role_kwargs:
         Optional ``role -> kwargs`` passed to that role's factory.
+    resource:
+        Index of the topology compute resource the default processing unit
+        runs on, taken modulo their count: replica ``i`` of a fleet passes
+        ``i`` and lands on the ``i``-th device, wrapping when there are more
+        replicas than devices.
     """
 
     def __init__(
@@ -59,8 +65,10 @@ class Runtime:
         *,
         overrides: Optional[Mapping[str, str]] = None,
         role_kwargs: Optional[Mapping[str, Mapping]] = None,
+        resource: int = 0,
     ):
         self.backend = backend
+        self._resource = resource
         overrides = dict(overrides or {})
         role_kwargs = dict(role_kwargs or {})
         info = registry.get_backend(backend)
@@ -150,13 +158,14 @@ class Runtime:
     # -- execution helpers --------------------------------------------------
     @property
     def processing_unit(self) -> ProcessingUnit:
-        """Default PU: first compute resource of the topology, initialized."""
+        """Default PU: the `resource`-th compute resource of the topology,
+        initialized."""
         if self._pu is None:
             resources = self.query_topology().all_compute_resources()
             if not resources:
                 raise RuntimeAssemblyError("topology exposes no compute resources")
             cm = self.compute_manager
-            self._pu = cm.create_processing_unit(resources[0])
+            self._pu = cm.create_processing_unit(resources[self._resource % len(resources)])
             cm.initialize(self._pu)
         return self._pu
 
@@ -170,8 +179,9 @@ class Runtime:
         cm = self.compute_manager
         state = cm.create_execution_state(unit, *args, **kwargs)
         future = cm.execute(self.processing_unit, state)
-        if len(self._inflight) > 64:
-            self._prune_inflight()
+        # a settled future holds its results, so keep only those in flight:
+        # a serve loop's results include each interval's copy of the KV pool
+        self._prune_inflight()
         self._inflight.append(future)
         return future
 

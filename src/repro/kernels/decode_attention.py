@@ -5,7 +5,8 @@ Decode is memory-bound (the whole cache streams HBM→VMEM once per step);
 the kernel therefore tiles the cache sequence dimension and keeps the
 online-softmax state in VMEM scratch, touching each cache byte exactly once.
 Slots beyond `pos` are masked (ring buffers for windowed layers are fully
-valid by construction once warm).
+valid by construction once warm). Positions ride in as a scalar-prefetch
+operand, read from SMEM by the body, as in `paged_decode_attention.py`.
 """
 from __future__ import annotations
 
@@ -15,26 +16,37 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from .ops import CompilerParams, MemorySpace
+from .ops import interpret_mode
 
 NEG_INF = -1e30
 
 
+def _block_len(S: int, target: int) -> int:
+    """Largest divisor of S that is at most `target` and a multiple of 16
+    (the bf16 sublane tile); S itself when it has none (a full dimension
+    is always a legal block)."""
+    for bs in range(min(target, S), 15, -1):
+        if S % bs == 0 and bs % 16 == 0:
+            return bs
+    return S
+
+
 def _kernel(
-    pos_ref,  # SMEM (1,)
-    q_ref,  # (1, H, hd)
+    pos_ref,  # scalar prefetch: positions (B,)
+    q_ref,  # (1, 1, groups, hd)
     k_ref,  # (1, 1, bs, hd)
     v_ref,  # (1, 1, bs, hd)
-    o_ref,  # (1, H, hd)
-    m_scr, l_scr, acc_scr,  # (H,1),(H,1),(H,hd)
+    o_ref,  # (1, 1, groups, hd)
+    m_scr, l_scr, acc_scr,  # (groups,1),(groups,1),(groups,hd)
     *,
     scale: float,
     groups: int,
     block_s: int,
     num_s_blocks: int,
 ):
+    b = pl.program_id(0)
     isb = pl.program_id(2)
 
     @pl.when(isb == 0)
@@ -43,7 +55,7 @@ def _kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    pos = pos_ref[0]
+    pos = pos_ref[b]
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (groups, hd)
     k = k_ref[0, 0].astype(jnp.float32)  # (bs, hd)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (groups, bs)
@@ -75,17 +87,17 @@ def decode_attention(
     *,
     scale: Optional[float] = None,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """q: (B, H, hd); k/v_cache: (B, S, KV, hd); pos: scalar or (B,).
     Returns (B, H, hd). The per-KV-head grid dim lets GQA share cache blocks
-    across the q-head group without replication."""
+    across the q-head group without replication. The cache is walked in
+    blocks of the largest 16-aligned divisor of S up to `block_s`."""
     B, H, hd = q.shape
     _, S, KV, _ = k_cache.shape
     groups = H // KV
     scale = scale if scale is not None else 1.0 / (hd**0.5)
-    block_s = min(block_s, S)
-    assert S % block_s == 0, (S, block_s)
+    block_s = _block_len(S, block_s)
     ns = S // block_s
 
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, dtype=jnp.int32), (B,))
@@ -99,25 +111,28 @@ def decode_attention(
         _kernel, scale=scale, groups=groups, block_s=block_s, num_s_blocks=ns
     )
 
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, KV, ns),
         in_specs=[
-            pl.BlockSpec(memory_space=MemorySpace.SMEM, block_shape=(1,), index_map=lambda b, h, i: (b,)),
-            pl.BlockSpec((1, 1, groups, hd), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_s, hd), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_s, hd), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, groups, hd), lambda b, h, i, ps: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_s, hd), lambda b, h, i, ps: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_s, hd), lambda b, h, i, ps: (b, h, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, groups, hd), lambda b, h, i: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, groups, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, groups, hd), lambda b, h, i, ps: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((groups, 1), jnp.float32),
             pltpu.VMEM((groups, 1), jnp.float32),
             pltpu.VMEM((groups, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, groups, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
-    )(pos_arr, qg.reshape(B, KV, groups, hd), kt, vt)
+        interpret=interpret_mode() if interpret is None else interpret,
+    )(pos_arr, qg, kt, vt)
     return out.reshape(B, H, hd)

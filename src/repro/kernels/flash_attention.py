@@ -21,9 +21,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from .ops import CompilerParams
+from .ops import interpret_mode
 
 NEG_INF = -1e30
 
@@ -104,7 +104,7 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """q: (B, Sq, H, hd); k,v: (B, Skv, KV, hd) -> (B, Sq, H, hd)."""
     B, Sq, H, hd = q.shape
@@ -148,9 +148,9 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
             pltpu.VMEM((block_q, hd), jnp.float32),  # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -14,9 +14,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from .ops import CompilerParams
+from .ops import interpret_mode
 
 
 def _kernel(x_ref, w_ref, b_ref, o_ref, acc_scr, *, act: str, nk: int):
@@ -44,7 +44,7 @@ def _kernel(x_ref, w_ref, b_ref, o_ref, acc_scr, *, act: str, nk: int):
 def fused_linear(
     x, w, b, *, act: str = "none",
     block_m: int = 128, block_n: int = 128, block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """x: (M, K); w: (K, N); b: (N,) -> (M, N)."""
     M, K = x.shape
@@ -65,10 +65,10 @@ def fused_linear(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(x, w, b.reshape(1, N))
 
 

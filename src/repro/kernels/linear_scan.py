@@ -19,9 +19,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from .ops import CompilerParams
+from .ops import interpret_mode
 
 
 def _kernel(
@@ -81,7 +81,7 @@ def gated_linear_scan(
     *,
     chunk: int = 128,
     initial_state=None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """q,k: (B,H,S,dk); v: (B,H,S,dv); log_a: (B,H,S).
     Returns (y (B,H,S,dv), final_state (B,H,dk,dv) fp32).
@@ -119,9 +119,9 @@ def gated_linear_scan(
             jax.ShapeDtypeStruct((B, H, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(q, k, v, la4)
     return y, s_fin

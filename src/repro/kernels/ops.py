@@ -2,35 +2,39 @@
 
 Models call these entry points; `impl` selects the implementation:
 
-* ``"ref"``   — the pure-jnp oracle (CPU tests, dry-run lowering).
-* ``"pallas"`` — the Pallas TPU kernel (interpret=True on CPU for
-  validation; compiled on real TPU).
+* ``"ref"``    — the pure-jnp oracle.
+* ``"pallas"`` — the Pallas TPU kernel: compiled on a TPU, run by the
+  Pallas interpreter on any other backend (`interpret_mode`).
+* ``"blocked"`` / ``"sequential"`` — jnp variants that bound memory.
 
-The default is resolved from the architecture config's ``use_pallas`` flag
-by the model code; benchmarks/tests pass `impl` explicitly.
+Where the serve path runs the Pallas kernels is decided here, from the
+platform, by `decode_impl`; tests and benchmarks pass `impl` explicitly.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
 
 from . import ref
 
-#: jax >= 0.5 renamed TPUCompilerParams -> CompilerParams and
-#: TPUMemorySpace -> MemorySpace; kernels import these aliases so they run
-#: on either release line.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-MemorySpace = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
 
-_INTERPRET = True  # this container is CPU-only; real TPU flips this off
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
-def set_interpret(value: bool) -> None:
-    global _INTERPRET
-    _INTERPRET = value
+def interpret_mode() -> bool:
+    """Pallas kernels run compiled on a TPU and interpreted everywhere else."""
+    return not on_tpu()
+
+
+def decode_impl() -> str:
+    """Single-token decode attention, dense and paged: the compiled Pallas
+    kernels on a TPU, the jnp oracle elsewhere. Training and prefill
+    attention stay on jnp on every platform: `flash_attention` (and the
+    SSM `linear_scan` kernel) define no VJP, and prefill runs once per
+    admission, not in the decode loop."""
+    return "pallas" if on_tpu() else "ref"
 
 
 def attention(q, k, v, *, causal=True, window=0, prefix_len=0, q_offset=0,
@@ -43,7 +47,7 @@ def attention(q, k, v, *, causal=True, window=0, prefix_len=0, q_offset=0,
         if kv_valid_len is None:
             return flash_attention.flash_attention(
                 q, k, v, causal=causal, window=window, prefix_len=prefix_len,
-                q_offset=q_offset, scale=scale, interpret=_INTERPRET,
+                q_offset=q_offset, scale=scale,
             )
     if impl == "blocked":
         from . import blocked
@@ -61,24 +65,22 @@ def attention(q, k, v, *, causal=True, window=0, prefix_len=0, q_offset=0,
     )
 
 
-def decode_attention(q, k_cache, v_cache, pos, *, window=0, scale=None, impl: str = "ref"):
-    if impl == "pallas":
+def decode_attention(q, k_cache, v_cache, pos, *, window=0, scale=None,
+                     impl: Optional[str] = None):
+    if (impl or decode_impl()) == "pallas":
         from . import decode_attention as da
 
-        return da.decode_attention(
-            q, k_cache, v_cache, pos, scale=scale, interpret=_INTERPRET
-        )
+        return da.decode_attention(q, k_cache, v_cache, pos, scale=scale)
     return ref.decode_attention(q, k_cache, v_cache, pos, window=window, scale=scale)
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *, window=0, scale=None,
-                           impl: str = "ref"):
-    if impl == "pallas":
+                           impl: Optional[str] = None):
+    if (impl or decode_impl()) == "pallas":
         from . import paged_decode_attention as pda
 
         return pda.paged_decode_attention(
-            q, k_pool, v_pool, page_table, pos, window=window, scale=scale,
-            interpret=_INTERPRET,
+            q, k_pool, v_pool, page_table, pos, window=window, scale=scale
         )
     return ref.paged_decode_attention(
         q, k_pool, v_pool, page_table, pos, window=window, scale=scale
@@ -90,8 +92,7 @@ def gated_linear_scan(q, k, v, log_a, *, chunk: int = 128, initial_state=None, i
         from . import linear_scan
 
         return linear_scan.gated_linear_scan(
-            q, k, v, log_a, chunk=chunk, initial_state=initial_state,
-            interpret=_INTERPRET,
+            q, k, v, log_a, chunk=chunk, initial_state=initial_state
         )
     if impl == "sequential":
         from . import blocked

@@ -21,7 +21,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
+
+from .ops import interpret_mode
 
 NEG_INF = -1e30
 
@@ -89,7 +91,7 @@ def paged_decode_attention(
     *,
     window: int = 0,
     scale: Optional[float] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """q: (B, H, hd); k/v_pool: (P, page, KV, hd); page_table: (B, n_pages)
     int32 physical page per logical page; pos: scalar or (B,) last valid
@@ -135,6 +137,6 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, groups, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(page_table.astype(jnp.int32), pos_arr, qg, kt, vt)
     return out.reshape(B, H, hd)

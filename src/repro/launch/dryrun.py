@@ -1,6 +1,9 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
+# ^ MUST precede every other import (jax locks platform and device count on
+# first init). The dry-run plans on placeholder CPU devices, so it never
+# contends for an accelerator held by another process.
 """Multi-pod dry-run: lower + compile every (architecture × input shape) on
 the production meshes, proving the distribution config is coherent without
 real hardware.

@@ -7,22 +7,24 @@ Topology component, never raw `jax.devices()` (DESIGN.md §2).
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
 
 from repro.core.stateless import Topology
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """Every mesh of the repo is built here, with Auto axes: the sharding
+    rules are written for propagation, not for explicit-sharding types."""
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def mesh_from_topology(
@@ -44,5 +46,5 @@ def mesh_from_topology(
         raise ValueError(f"{per_pod} chips/pod not divisible by model={model_parallelism}")
     data = per_pod // model_parallelism
     if n_pods > 1:
-        return jax.make_mesh((n_pods, data, model_parallelism), ("pod", "data", "model"))
-    return jax.make_mesh((data, model_parallelism), ("data", "model"))
+        return make_mesh((n_pods, data, model_parallelism), ("pod", "data", "model"))
+    return make_mesh((data, model_parallelism), ("data", "model"))

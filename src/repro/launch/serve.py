@@ -18,27 +18,48 @@ the device-resident decode loop (`--sync-interval` fused ticks per host
 sync). ``--kv-mode dense`` is the per-slot dense-cache baseline. Both apply
 per worker in fleet mode.
 
+`main` returns a `ServeReport`: the tokens and finish reason of every
+request, as the chosen mode produced them, so programmatic callers (the
+chip smoke test) check what this entry point served.
+
 The channel-driven multi-instance front door (2 producers + 1 server over
 the localsim fabric) is wired in examples/serve_demo.py.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.core.runtime import Runtime
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.serve.engine import ServeEngine
-from repro.serve.scheduler import ContinuousBatchingScheduler
+from repro.serve.scheduler import ContinuousBatchingScheduler, Request
 from repro.serve.workload import shared_prefix_requests, synthetic_requests
 from repro.train import checkpoint as ckpt
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class ServeReport:
+    """What one `main` call served."""
+
+    #: rid -> {"tokens": [...], "finish_reason": str} (or {"error": str})
+    results: Dict[str, dict]
+    #: mode-specific counters. Continuous mode: the radix cache's "prefix"
+    #: counters (None without it); fleet mode: the router's stats, with
+    #: each worker's device under "per_worker_device"
+    stats: dict = dataclasses.field(default_factory=dict)
+
+
+def main(argv=None, *, requests: Optional[Sequence[Request]] = None) -> ServeReport:
+    """Serve a workload as the command line says. `requests`, when given,
+    replaces the synthetic workload the flags would build."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
     ap.add_argument("--reduced", action="store_true")
@@ -75,6 +96,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     model = build(cfg)
     params, _ = model.init(jax.random.PRNGKey(0))
@@ -83,9 +105,9 @@ def main(argv=None):
         params = jax.tree_util.tree_map(jax.numpy.asarray, restored["params"])
         print(f"restored weights from {args.ckpt_dir}")
 
-    prefix = cfg.vision_tokens if cfg.family == "vlm" else 0
-    max_len = prefix + args.prompt_len + args.steps
-    if args.prefix_share > 0:
+    if requests is not None:
+        requests = list(requests)
+    elif args.prefix_share > 0:
         requests = shared_prefix_requests(
             cfg.vocab_size,
             args.requests,
@@ -102,6 +124,8 @@ def main(argv=None):
             steps_range=(max(1, args.steps // 2), args.steps + 1),
         )
     total_tokens = sum(r.max_new_tokens for r in requests)
+    prefix = cfg.vision_tokens if cfg.family == "vlm" else 0
+    max_len = prefix + max(len(r.prompt) + r.max_new_tokens for r in requests)
 
     t0 = time.time()
     if args.mode == "fleet":
@@ -136,16 +160,20 @@ def main(argv=None):
         print(f"served {len(requests)} requests / {total_tokens} tokens in {dt:.2f}s "
               f"({total_tokens / dt:.1f} tok/s, mode=fleet, workers={args.workers}, "
               f"backend={args.backend})")
-        return
+        return ServeReport(results=out.results, stats=stats)
     # context-managed Runtime: the default processing unit is finalized on
     # exit, so repeated invocations never leak backend worker threads
+    results: Dict[str, dict] = {}
+    stats: dict = {}
     with Runtime(args.backend) as runtime:
         if args.mode == "serial":
             engine = ServeEngine(model, params, max_len=max_len, runtime=runtime)
             for r in requests:
                 prompt = np.asarray([r.prompt], dtype=np.int32)
                 result = engine.generate(prompt, steps=r.max_new_tokens)
-                print(f"{r.rid}: {result.tokens[0][:8].tolist()}...")
+                tokens = result.tokens[0].tolist()
+                results[r.rid] = {"tokens": tokens, "finish_reason": "length"}
+                print(f"{r.rid}: {tokens[:8]}...")
         else:
             sched = ContinuousBatchingScheduler(
                 model, params, max_batch=args.max_batch, max_len=max_len, runtime=runtime,
@@ -153,14 +181,16 @@ def main(argv=None):
                 pool_pages=args.pool_pages, sync_interval=args.sync_interval,
                 prefix_cache=args.prefix_cache,
             )
-            results = sched.serve(requests)
+            finished = sched.serve(requests)
             for r in requests:
-                fin = results[r.rid]
+                fin = finished[r.rid]
+                results[r.rid] = {"tokens": fin.tokens, "finish_reason": fin.finish_reason}
                 print(f"{fin.rid}: {fin.tokens[:8]}... ({fin.finish_reason})")
             print(f"scheduler: {sched.ticks} decode ticks for {len(requests)} requests"
                   f" (kv_mode={args.kv_mode})")
+            prog = sched.active_progress()
+            stats = {"prefix": prog.prefix}
             if args.kv_mode == "paged":
-                prog = sched.active_progress()
                 print(f"kv pool: {prog.pages_used} pages used / "
                       f"{prog.pages_free} free after drain")
                 if prog.prefix is not None:
@@ -172,6 +202,7 @@ def main(argv=None):
     dt = time.time() - t0
     print(f"served {len(requests)} requests / {total_tokens} tokens in {dt:.2f}s "
           f"({total_tokens / dt:.1f} tok/s, mode={args.mode}, backend={args.backend})")
+    return ServeReport(results=results, stats=stats)
 
 
 if __name__ == "__main__":

@@ -1,9 +1,9 @@
 """End-to-end training driver.
 
-The launcher path is pure HiCR (DESIGN.md §3): topology managers discover
-(or declare) the hardware; the mesh is built from the HiCR Topology; the
-train step is an ExecutionUnit dispatched through the SPMD compute manager;
-checkpoints commit atomically and training resumes from the latest one.
+The launcher path is pure HiCR (DESIGN.md §3): a data-parallel mesh spans
+the visible devices (launch/mesh.py); the train step is an ExecutionUnit
+dispatched through the SPMD compute manager; checkpoints commit atomically
+and training resumes from the latest one.
 
     PYTHONPATH=src python -m repro.launch.train --arch minitron-8b --reduced \
         --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/run1 --ckpt-every 50
@@ -11,40 +11,19 @@ checkpoints commit atomically and training resumes from the latest one.
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 import jax
-import numpy as np
 
-from repro.backends import hostcpu, jaxdev, spmd, tpu_spec
+from repro.backends import spmd
 from repro.configs import ShapeConfig, get_config
-from repro.core.managers import ManagerSet
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import build
-from repro.sharding import partition
 from repro.train import checkpoint as ckpt
 from repro.train import optimizer as opt_lib
 from repro.train.data import DataState, SyntheticTokenStream
 from repro.train.train_step import TrainConfig, init_train_state, make_train_step
-
-
-def discover_mesh(use_spec: bool = False):
-    """HiCR path: TopologyManagers -> Topology -> mesh."""
-    managers = ManagerSet(
-        topology_managers=(
-            (tpu_spec.SpecTopologyManager(),) if use_spec else (jaxdev.JaxTopologyManager(), hostcpu.HostTopologyManager())
-        )
-    )
-    topo = managers.query_full_topology()
-    try:
-        from repro.launch.mesh import mesh_from_topology
-
-        return mesh_from_topology(topo)
-    except ValueError:
-        # CPU fallback: 1-device mesh over whatever jax exposes
-        n = len(jax.devices())
-        return jax.make_mesh((n, 1), ("data", "model")), topo
-    return None
 
 
 def main(argv=None):
@@ -62,6 +41,7 @@ def main(argv=None):
     ap.add_argument("--optimizer", default="adamw")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     shape = ShapeConfig("cli", seq_len=args.seq, global_batch=args.batch, kind="train")
     model = build(cfg)
@@ -73,7 +53,7 @@ def main(argv=None):
 
     # ---- HiCR launcher: topology -> mesh -> SPMD compute manager ----------
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh = make_mesh((n_dev, 1), ("data", "model"))
     cpm = spmd.SpmdComputeManager(mesh)
     pu = cpm.create_processing_unit(cpm.mesh_compute_resource())
     cpm.initialize(pu)

@@ -69,8 +69,8 @@ def self_attention(
 
 
 def _impl(cfg: ArchConfig) -> str:
-    if cfg.use_pallas:
-        return "pallas"
+    # training/prefill attention stays on jnp on every platform (see
+    # ops.decode_impl); only the decode kernels follow the platform
     return "blocked" if cfg.attention_impl == "blocked" else "ref"
 
 
@@ -156,12 +156,11 @@ def decode_self_attention(
     slot = (pos % window) if window and S_buf == window else pos
     k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
     v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
-    impl = "pallas" if cfg.use_pallas else "ref"  # decode stays unblocked (O(S) already)
     # For ring buffers every slot holds an in-window position; validity is
     # handled by `pos` (ref.decode_attention masks slots > pos only when the
     # buffer is longer than the written range).
     eff_pos = jnp.minimum(pos, S_buf - 1)
-    out = ops.decode_attention(q[:, 0], k_cache, v_cache, eff_pos, window=window, impl=impl)
+    out = ops.decode_attention(q[:, 0], k_cache, v_cache, eff_pos, window=window)
     proj = jnp.einsum("bhk,hkd->bd", out, p["wo"].astype(x.dtype))
     return proj[:, None, :], (k_cache, v_cache)
 
@@ -349,10 +348,8 @@ def paged_decode_self_attention(
     # ring buffers: every slot holds an in-window position once warm
     S_eff = table.shape[1] * page_size
     eff_pos = jnp.minimum(pos, S_eff - 1)
-    impl = "pallas" if cfg.use_pallas else "ref"
     out = ops.paged_decode_attention(
-        q[:, 0], pool_k, pool_v, table, eff_pos,
-        window=0 if is_ring else window, impl=impl,
+        q[:, 0], pool_k, pool_v, table, eff_pos, window=0 if is_ring else window
     )
     proj = jnp.einsum("bhk,hkd->bd", out, p["wo"].astype(x.dtype))
     return proj[:, None, :], (pool_k, pool_v)

@@ -28,8 +28,6 @@ from .common import ParamBuilder, rms_norm
 
 
 def _impl(cfg: ArchConfig) -> str:
-    if cfg.use_pallas:
-        return "pallas"
     return "sequential" if cfg.ssd_impl == "sequential" else "ref"
 
 

@@ -37,6 +37,8 @@ import zlib
 from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence
 
+import jax
+
 from repro.core.definitions import FutureTimeoutError, InstanceFailedError
 from repro.core.runtime import Runtime
 from repro.frontends.channels import (
@@ -125,10 +127,17 @@ def make_worker_entry(model, params, cfg: FleetConfig) -> Callable:
             cm, mm, tag=TAG_CTL + rank, capacity=cfg.ctl_capacity,
             msg_size=cfg.msg_size, timeout=cfg.connect_timeout,
         )
-        with Runtime(cfg.worker_backend) as rt:
+        # rank 0 is the router, so worker ranks count from 1: the first
+        # worker serves on the topology's first compute resource, and so on.
+        # A jaxdev worker keeps its weights, and every array it makes outside
+        # an execution unit (pool, page tables, control rows), on that device
+        # (`jax.default_device` is thread-local).
+        with Runtime(cfg.worker_backend, resource=rank - 1) as rt, \
+                jax.default_device(_jax_device(rt)):
             sched = ContinuousBatchingScheduler(
-                model, params, max_batch=cfg.max_batch, max_len=cfg.max_len,
-                runtime=rt, kv_mode=cfg.kv_mode, page_size=cfg.page_size,
+                model, jax.device_put(params, _jax_device(rt)),
+                max_batch=cfg.max_batch, max_len=cfg.max_len, runtime=rt,
+                kv_mode=cfg.kv_mode, page_size=cfg.page_size,
                 pool_pages=cfg.pool_pages, sync_interval=cfg.sync_interval,
                 prefix_cache=cfg.prefix_cache,
             )
@@ -141,6 +150,7 @@ def make_worker_entry(model, params, cfg: FleetConfig) -> Callable:
                 prog = sched.active_progress()
                 body = {
                     "rank": rank,
+                    "device": rt.processing_unit.compute_resource.device_id,
                     "free_slots": prog.free_slots,
                     "pages_free": prog.pages_free,
                     "active": sched.active_count,
@@ -167,6 +177,13 @@ def make_worker_entry(model, params, cfg: FleetConfig) -> Callable:
                     server.wait_for_arrival(cfg.idle_wait)
 
     return worker_main
+
+
+def _jax_device(rt: Runtime) -> Optional[jax.Device]:
+    """The JAX device behind a Runtime's processing unit (jaxdev), or None
+    for backends whose processing units are not devices."""
+    context = rt.processing_unit.context
+    return context if isinstance(context, jax.Device) else None
 
 
 @dataclasses.dataclass
@@ -197,6 +214,7 @@ class _WorkerHandle:
     ctl: SPSCConsumer
     alive: bool = True
     reported: bool = False
+    device: Optional[str] = None  # compute resource the worker serves on
     free_slots: int = 0
     pages_free: Optional[int] = None
     prefix: Optional[dict] = None  # last reported radix-cache counters
@@ -240,11 +258,22 @@ class FleetRouter:
 
     # -- instance lifecycle ---------------------------------------------------
     def spawn_workers(self, count: int) -> None:
-        """Template → create → attach: the §3.1.1 creation step."""
+        """Template → create → attach: the §3.1.1 creation step. Returns
+        once every new worker has reported its capacity (or died), so that
+        admission balances over all of them instead of filling whichever
+        reported first."""
         template = self.im.create_instance_template(min_compute_resources=1)
-        for inst in self.im.create_instances(count, template):
-            self._attach(inst)
+        handles = [self._attach(inst) for inst in self.im.create_instances(count, template)]
         self._spawned += count
+        deadline = time.monotonic() + self.cfg.connect_timeout
+        while not all(h.reported or not h.instance.is_live() for h in handles):
+            if time.monotonic() >= deadline:
+                raise FutureTimeoutError(
+                    f"workers spawned but silent after {self.cfg.connect_timeout}s"
+                )
+            for h in handles:
+                self._drain_ctl(h)
+            time.sleep(0.001)
 
     def respawn_worker(self) -> _WorkerHandle:
         """Create one replacement worker from the same template (the
@@ -363,6 +392,7 @@ class FleetRouter:
             if raw is None:
                 return
             body = json.loads(bytes(raw).rstrip(b"\0").decode())
+            h.device = body.get("device")
             h.free_slots = int(body.get("free_slots", 0))
             h.pages_free = body.get("pages_free")
             h.prefix = body.get("prefix")
@@ -538,6 +568,7 @@ class FleetRouter:
             "workers_killed": self._killed,
             "restarted": restarted,
             "per_worker_settled": {h.idx: h.settled for h in self.workers},
+            "per_worker_device": {h.idx: h.device for h in self.workers},
             # last reported radix-cache counters per worker (None when the
             # prefix cache is off): the fleet's warm-cache evidence
             "per_worker_prefix": {h.idx: h.prefix for h in self.workers},

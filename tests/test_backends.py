@@ -235,6 +235,19 @@ class TestJaxDev:
         assert len(topo.get_devices()) >= 1
         assert len(topo.all_memory_spaces()) >= 1
 
+    def test_accelerator_without_bytes_limit_is_an_error(self):
+        """An accelerator's memory size comes from the device or nowhere:
+        no assumed default hides a device that reports none."""
+
+        class Silent:
+            platform, id, device_kind = "tpu", 0, "TPU v5 lite"
+
+            def memory_stats(self):
+                return {}
+
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            jaxdev._memory_bytes(Silent())
+
     def test_memory_alloc(self):
         mm = jaxdev.JaxMemoryManager()
         space = mm.memory_spaces()[0]

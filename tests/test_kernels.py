@@ -1,4 +1,4 @@
-"""Pallas kernels vs pure-jnp oracles (interpret=True on CPU).
+"""Pallas kernels vs pure-jnp oracles (interpreted off a TPU).
 
 Per instructions: sweep shapes/dtypes per kernel and assert_allclose against
 the ref.py oracle; hypothesis drives randomized shape/value generation for
@@ -15,8 +15,6 @@ except ImportError:  # missing dep: property tests skip, the rest still run
     from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
-
-ops.set_interpret(True)
 
 
 def _rand(key, shape, dtype, scale=1.0):

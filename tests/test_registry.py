@@ -1,6 +1,10 @@
 """Backend registry (`core/registry.py`) and the `Runtime` facade
 (`core/runtime.py`): build-by-name, the capability table, error paths, and
 backend-agnostic execution across hostcpu and jaxdev."""
+import gc
+import weakref
+
+import jax.numpy as jnp
 import pytest
 
 from repro.core import registry
@@ -75,6 +79,33 @@ class TestRuntime:
         rt = Runtime("hostcpu")
         assert rt.processing_unit is rt.processing_unit
         rt.finalize()
+
+    @pytest.mark.parametrize("backend", ["hostcpu", "jaxdev"])
+    def test_resource_indexes_the_topology(self, backend):
+        with Runtime(backend) as rt:
+            resources = rt.query_topology().all_compute_resources()
+        for i in (0, 1, len(resources) + 1):
+            with Runtime(backend, resource=i) as rt:
+                assert rt.processing_unit.compute_resource == resources[i % len(resources)]
+
+    @pytest.mark.parametrize("backend", ["hostcpu", "jaxdev"])
+    def test_settled_runs_release_their_arguments(self, backend):
+        """Only the latest execution may keep its arguments alive, and no
+        reference cycle may keep the others: a serve loop's arguments are
+        its weights and each interval's copy of the KV pool."""
+        gc.disable()  # refcounting alone must free them
+        try:
+            with Runtime(backend) as rt:
+                unit = rt.create_execution_unit(lambda x: x * 2, name="double")
+                refs = []
+                for i in range(4):
+                    x = jnp.full((8,), float(i))
+                    refs.append(weakref.ref(x))
+                    rt.run(unit, x)
+                    del x
+                assert [r() is None for r in refs] == [True, True, True, False]
+        finally:
+            gc.enable()
 
     def test_role_overrides_mix_backends(self):
         # coroutine has no topology role; borrow hostcpu's (Table 1 mixing)

@@ -6,6 +6,7 @@ import numpy as np
 
 from repro.backends import spmd
 from repro.configs import ShapeConfig, get_config
+from repro.launch.mesh import make_mesh
 from repro.models import build
 from repro.serve.engine import ServeEngine
 from repro.train import checkpoint as ckpt
@@ -21,7 +22,7 @@ def test_train_checkpoint_restore_serve_roundtrip(tmp_path):
     ocfg = opt_lib.OptimizerConfig(name="adamw", learning_rate=1e-3, warmup_steps=2)
 
     # ---- train 3 steps through the SPMD compute manager (HiCR path) -------
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     cpm = spmd.SpmdComputeManager(mesh)
     pu = cpm.create_processing_unit(cpm.mesh_compute_resource())
     cpm.initialize(pu)
