@@ -16,6 +16,11 @@ futures, transfer events, channel ops);
 ``run()`` is the synchronous shim (``submit(...).result()``). A Runtime is
 a context manager — ``with Runtime(...) as rt:`` finalizes the default
 processing unit on exit, so worker threads are never leaked.
+
+Each Runtime owns a `Tracer` (``rt.tracer``, off by default). When it is on,
+``run()`` records ``hicr.run:<unit>`` from submit to result, with the
+blocking wait as its child ``hicr.wait:<unit>``; code above the Runtime
+(the serve scheduler) opens its own spans on the same tracer.
 """
 from __future__ import annotations
 
@@ -28,6 +33,7 @@ from .events import Event, Future
 from .managers import ManagerSet
 from .stateful import ProcessingUnit
 from .stateless import ExecutionUnit, Topology
+from .tracing import Tracer
 
 #: Roles a Runtime will try to build, in build order.
 _ASSEMBLY_ROLES = ("topology", "memory", "communication", "compute", "instance")
@@ -94,6 +100,7 @@ class Runtime:
         self._pu: Optional[ProcessingUnit] = None
         self._topology: Optional[Topology] = None
         self._inflight: list[Future] = []
+        self.tracer = Tracer()
 
     # -- manager access -----------------------------------------------------
     @property
@@ -197,7 +204,13 @@ class Runtime:
 
     def run(self, unit: ExecutionUnit, *args, **kwargs):
         """Synchronous shim over `submit`: dispatch, block, return/raise."""
-        return self.submit(unit, *args, **kwargs).result()
+        tr = self.tracer
+        if not tr.enabled:
+            return self.submit(unit, *args, **kwargs).result()
+        with tr.span(f"hicr.run:{unit.name}"):
+            future = self.submit(unit, *args, **kwargs)
+            with tr.span(f"hicr.wait:{unit.name}"):
+                return future.result()
 
     def drive(
         self,

@@ -29,6 +29,11 @@ prefill's gather, and only the uncached tail runs through the model.
 Completion *returns* pages to the cache instead of freeing them; page
 pressure LRU-evicts unreferenced cache pages before refusing admission.
 
+With the Runtime's tracer on (``runtime.tracer.enable()``), admission
+records ``serve.admit`` with its phases and each step records
+``serve.step`` with the slots it decoded and the tokens they gave
+(ARCHITECTURE.md, "Tracing"); off, each span costs one attribute check.
+
 Token semantics match the serial `ServeEngine.generate` exactly in both
 modes: the first emitted token is the greedy pick from the prefill logits;
 each subsequent token comes from one decode step at the request's own
@@ -152,6 +157,7 @@ class ContinuousBatchingScheduler:
         self._free: deque[int] = deque(range(max_batch))
         self._finished: List[FinishedRequest] = []
         self.ticks = 0
+        self._tracer = self.decoder.rt.tracer
 
     # -- introspection ------------------------------------------------------
     @property
@@ -190,6 +196,15 @@ class ContinuousBatchingScheduler:
         reserve the request's worst-case pages (page-availability admission
         control); requests finishing at their very first token are completed
         without consuming a slot."""
+        with self._tracer.span("serve.admit", rid=request.rid,
+                               prompt_len=len(request.prompt)) as sp:
+            outcome = self._admit(request, sp)
+            sp.set(outcome=outcome)
+        return outcome in ("seated", "finished")
+
+    def _admit(self, request: Request, sp) -> str:
+        """`try_admit`'s body; returns its outcome: ``seated``, ``finished``
+        (at the first token), ``no_slot`` or ``no_pages``."""
         if request.max_new_tokens < 1:
             raise ValueError(f"request {request.rid!r}: max_new_tokens must be >= 1")
         prompt_len = len(request.prompt)
@@ -202,8 +217,9 @@ class ContinuousBatchingScheduler:
         if any(row is not None and row.request.rid == request.rid for row in self._table):
             raise ValueError(f"request id {request.rid!r} is already active")
         if not self._free:
-            return False
+            return "no_slot"
 
+        tr = self._tracer
         pages_total = new_pages = 0
         m = None  # prefix-cache match (None when the cache is off)
         if self.kv_mode == "paged":
@@ -211,25 +227,28 @@ class ContinuousBatchingScheduler:
             kv = self.decoder.kv
             pages_total = layout.pages_for(total_positions)
             n_shared = 0
-            if self.prefix is not None:
-                m = self.prefix.match(request.prompt)
-                n_shared = len(m.nodes)
-            # shared pages are already resident: only the new ones need
-            # reserving (the worst case shrinks with the matched prefix)
-            new_pages = pages_total - n_shared
-            if new_pages > kv.capacity:
-                raise ValueError(
-                    f"request {request.rid!r} needs {new_pages} KV pages, "
-                    f"pool capacity is {kv.capacity}"
-                )
-            if m is not None:
-                self.prefix.lock(m)
-            if not kv.reserve(new_pages):
-                # page pressure: LRU-evict cache-only pages before refusing
+            with tr.span("serve.admit.match"):
                 if self.prefix is not None:
-                    self.prefix.evict(new_pages - kv.pages_available)
-                if not kv.reserve(new_pages):
-                    if m is not None and self.active_count == 0:
+                    m = self.prefix.match(request.prompt)
+                    n_shared = len(m.nodes)
+                # shared pages are already resident: only the new ones need
+                # reserving (the worst case shrinks with the matched prefix)
+                new_pages = pages_total - n_shared
+                if new_pages > kv.capacity:
+                    raise ValueError(
+                        f"request {request.rid!r} needs {new_pages} KV pages, "
+                        f"pool capacity is {kv.capacity}"
+                    )
+                if m is not None:
+                    self.prefix.lock(m)
+            with tr.span("serve.admit.reserve") as rsp:
+                evicted = 0
+                reserved = kv.reserve(new_pages)
+                if not reserved and self.prefix is not None:
+                    # page pressure: LRU-evict cache-only pages before refusing
+                    evicted += self.prefix.evict(new_pages - kv.pages_available)
+                    reserved = kv.reserve(new_pages)
+                    if not reserved and m is not None and self.active_count == 0:
                         # nothing in flight will ever free pages, and our
                         # own lock may be what pins every evictable page:
                         # demote the match to a miss so eviction can reclaim
@@ -242,26 +261,32 @@ class ContinuousBatchingScheduler:
                                 f"request {request.rid!r} needs {new_pages} KV "
                                 f"pages uncached, pool capacity is {kv.capacity}"
                             )
-                        self.prefix.evict(new_pages - kv.pages_available)
-                    if not kv.reserve(new_pages):
-                        if m is not None:
-                            self.prefix.unlock(m)
-                        return False  # retry once pages free up
+                        evicted += self.prefix.evict(new_pages - kv.pages_available)
+                        reserved = kv.reserve(new_pages)
+                rsp.set(evicted=evicted)
+            if not reserved:
+                if m is not None:
+                    self.prefix.unlock(m)
+                return "no_pages"  # retry once pages free up
 
         try:
-            if self.prefix is not None:
-                # shared-prefix decoders always admit through the gather
-                # unit (a miss — matched or demoted — gathers null pages)
-                off = m.matched_len if m is not None else 0
-                row = (
-                    self._gather_row(m) if m is not None
-                    else np.zeros((self.decoder.layout.n_pages_seq,), np.int32)
-                )
-                first, state = self.decoder.prefill_prefix(
-                    request.prompt[off:], row, off
-                )
-            else:
-                first, state = self.decoder.prefill(request.prompt)
+            with tr.span("serve.admit.prefill") as psp:
+                if self.prefix is not None:
+                    # shared-prefix decoders always admit through the gather
+                    # unit (a miss — matched or demoted — gathers null pages)
+                    off = m.matched_len if m is not None else 0
+                    row = (
+                        self._gather_row(m) if m is not None
+                        else np.zeros((self.decoder.layout.n_pages_seq,), np.int32)
+                    )
+                    sp.set(matched_len=off)
+                    psp.set(tail_len=prompt_len - off)
+                    first, state = self.decoder.prefill_prefix(
+                        request.prompt[off:], row, off
+                    )
+                else:
+                    psp.set(tail_len=prompt_len)
+                    first, state = self.decoder.prefill(request.prompt)
         except BaseException:
             if new_pages:  # a failed prefill must not strand the reservation
                 self.decoder.kv.free((), unreserve=new_pages)
@@ -277,45 +302,47 @@ class ContinuousBatchingScheduler:
                     self.prefix.unlock(m)  # nothing committed: no donation
                 self.prefix.note(m, prompt_len)
             self._finished.append(self._finish(request, emitted))
-            return True
-        slot = self._free.popleft()
-        if self.kv_mode == "dense":
-            self.decoder.load(slot, state, first, self._prefix + prompt_len)
-            row = _Active(request=request, slot=slot, emitted=emitted)
-        else:
-            layout = self.decoder.layout
-            shared = m.shared_pages if m is not None else []
-            n_shared = len(shared)
-            # draw pages for everything prefill wrote + the first decode
-            # write; the rest of the reservation is drawn as the slot grows
-            pages_now = layout.pages_for(self._prefix + prompt_len + 1)
-            drawn = self.decoder.kv.draw(pages_now - n_shared)
-            self._page_table[slot, :] = 0
-            self._page_table[slot, :n_shared] = shared
-            self._page_table[slot, n_shared : n_shared + len(drawn)] = drawn
-            # shared pages are read-only: the commit scatters the dense
-            # state's prefix region into the null page instead
-            commit_row = self._page_table[slot].copy()
-            commit_row[:n_shared] = 0
-            self.decoder.load(
-                slot, state, first, self._prefix + prompt_len,
-                steps_left=request.max_new_tokens - 1,
-                eos_id=request.eos_id,
-                capacity=pages_total * layout.page_size,
-                full_row=commit_row,
-            )
-            if self.prefix is not None:
-                if m is not None:
-                    self.prefix.unlock_boundary(m)  # its content is copied now
-                self.prefix.note(m, prompt_len)
-            self._pos_host[slot] = self._prefix + prompt_len
-            row = _Active(
-                request=request, slot=slot, emitted=emitted,
-                pages=drawn, reserved_left=pages_total - pages_now,
-                shared=shared,
-            )
-        self._table[slot] = row
-        return True
+            return "finished"
+        with tr.span("serve.admit.commit"):
+            slot = self._free.popleft()
+            if self.kv_mode == "dense":
+                self.decoder.load(slot, state, first, self._prefix + prompt_len)
+                row = _Active(request=request, slot=slot, emitted=emitted)
+            else:
+                layout = self.decoder.layout
+                shared = m.shared_pages if m is not None else []
+                n_shared = len(shared)
+                # draw pages for everything prefill wrote + the first decode
+                # write; the rest of the reservation is drawn as the slot
+                # grows
+                pages_now = layout.pages_for(self._prefix + prompt_len + 1)
+                drawn = self.decoder.kv.draw(pages_now - n_shared)
+                self._page_table[slot, :] = 0
+                self._page_table[slot, :n_shared] = shared
+                self._page_table[slot, n_shared : n_shared + len(drawn)] = drawn
+                # shared pages are read-only: the commit scatters the dense
+                # state's prefix region into the null page instead
+                commit_row = self._page_table[slot].copy()
+                commit_row[:n_shared] = 0
+                self.decoder.load(
+                    slot, state, first, self._prefix + prompt_len,
+                    steps_left=request.max_new_tokens - 1,
+                    eos_id=request.eos_id,
+                    capacity=pages_total * layout.page_size,
+                    full_row=commit_row,
+                )
+                if self.prefix is not None:
+                    if m is not None:
+                        self.prefix.unlock_boundary(m)  # its content is copied
+                    self.prefix.note(m, prompt_len)
+                self._pos_host[slot] = self._prefix + prompt_len
+                row = _Active(
+                    request=request, slot=slot, emitted=emitted,
+                    pages=drawn, reserved_left=pages_total - pages_now,
+                    shared=shared,
+                )
+            self._table[slot] = row
+        return "seated"
 
     def _gather_row(self, m) -> np.ndarray:
         """Page-table row for the tail prefill's prefix gather: the matched
@@ -353,12 +380,14 @@ class ContinuousBatchingScheduler:
         done, self._finished = self._finished, []
         if self.active_count == 0:
             return done
-        if self.kv_mode == "dense":
-            return done + self._step_dense()
-        return done + self._step_paged()
+        with self._tracer.span("serve.step") as sp:
+            if self.kv_mode == "dense":
+                return done + self._step_dense(sp)
+            return done + self._step_paged(sp)
 
-    def _step_dense(self) -> List[FinishedRequest]:
+    def _step_dense(self, sp) -> List[FinishedRequest]:
         done: List[FinishedRequest] = []
+        sp.set(slots=self.max_batch, tokens=self.active_count)
         new_tokens = self.decoder.step()
         self.ticks += 1
         # the eviction ceiling comes from the decoder's actual allocated
@@ -379,12 +408,14 @@ class ContinuousBatchingScheduler:
                 self._free.append(slot)
         return done
 
-    def _grow_pages(self) -> None:
+    def _grow_pages(self) -> int:
         """Before an interval: draw enough reserved pages for every active
         slot to cover `sync_interval` more positions. Reservations were made
-        at admission, so a draw can never fail mid-flight."""
+        at admission, so a draw can never fail mid-flight. Returns the
+        number of pages drawn."""
         layout = self.decoder.layout
         pos = self._pos_host
+        n_drawn = 0
         for slot, row in enumerate(self._table):
             if row is None or not row.reserved_left:
                 continue
@@ -396,33 +427,43 @@ class ContinuousBatchingScheduler:
                 self._page_table[slot, filled : filled + delta] = drawn
                 row.pages.extend(drawn)
                 row.reserved_left -= delta
+                n_drawn += delta
+        return n_drawn
 
-    def _step_paged(self) -> List[FinishedRequest]:
+    def _step_paged(self, sp) -> List[FinishedRequest]:
         done: List[FinishedRequest] = []
-        self._grow_pages()
-        out_buf, done_mask, pos = self.decoder.run_interval(self._page_table)
+        tr = self._tracer
+        with tr.span("serve.step.grow") as grow:
+            grow.set(drawn=self._grow_pages())
+        with tr.span("serve.step.interval"):
+            out_buf, done_mask, pos = self.decoder.run_interval(self._page_table)
         self._pos_host[:] = pos
         self.ticks += self.decoder.sync_interval
-        for slot, row in enumerate(self._table):
-            if row is None:
-                continue
-            ticks = out_buf[slot]
-            row.emitted.extend(int(t) for t in ticks[ticks >= 0])
-            if done_mask[slot]:
-                done.append(self._finish(row.request, row.emitted))
-                if self.prefix is not None:
-                    # return pages through the radix cache: full pages of the
-                    # written sequence are donated/shared, the rest freed.
-                    # Positions written: the prompt plus every emitted token
-                    # that was fed back (the last one never was).
-                    seq = list(row.request.prompt) + row.emitted[:-1]
-                    self.prefix.commit(seq, row.shared + row.pages)
-                    self.decoder.kv.free((), unreserve=row.reserved_left)
-                else:
-                    self.decoder.kv.free(row.pages, unreserve=row.reserved_left)
-                self._page_table[slot, :] = 0
-                self._table[slot] = None
-                self._free.append(slot)
+        with tr.span("serve.step.harvest"):
+            for slot, row in enumerate(self._table):
+                if row is None:
+                    continue
+                ticks = out_buf[slot]
+                row.emitted.extend(int(t) for t in ticks[ticks >= 0])
+                if done_mask[slot]:
+                    done.append(self._finish(row.request, row.emitted))
+                    if self.prefix is not None:
+                        # return pages through the radix cache: full pages of
+                        # the written sequence are donated/shared, the rest
+                        # freed. Positions written: the prompt plus every
+                        # emitted token that was fed back (the last one never
+                        # was).
+                        seq = list(row.request.prompt) + row.emitted[:-1]
+                        self.prefix.commit(seq, row.shared + row.pages)
+                        self.decoder.kv.free((), unreserve=row.reserved_left)
+                    else:
+                        self.decoder.kv.free(row.pages, unreserve=row.reserved_left)
+                    self._page_table[slot, :] = 0
+                    self._table[slot] = None
+                    self._free.append(slot)
+        if tr.enabled:
+            sp.set(slots=self.max_batch * self.decoder.sync_interval,
+                   tokens=int((out_buf >= 0).sum()), finished=len(done))
         return done
 
     # -- batch driver --------------------------------------------------------
