@@ -345,9 +345,10 @@ def paged_decode_self_attention(
     pool_k = pool_k.at[phys, offset].set(k[:, 0].astype(pool_k.dtype))
     pool_v = pool_v.at[phys, offset].set(v[:, 0].astype(pool_v.dtype))
 
-    # ring buffers: every slot holds an in-window position once warm
+    # ring buffers: every slot holds an in-window position once warm;
+    # inactive slots pass -1, so the Pallas kernel walks none of their pages
     S_eff = table.shape[1] * page_size
-    eff_pos = jnp.minimum(pos, S_eff - 1)
+    eff_pos = jnp.where(active, jnp.minimum(pos, S_eff - 1), -1)
     out = ops.paged_decode_attention(
         q[:, 0], pool_k, pool_v, table, eff_pos, window=0 if is_ring else window
     )

@@ -31,8 +31,10 @@ pressure LRU-evicts unreferenced cache pages before refusing admission.
 
 With the Runtime's tracer on (``runtime.tracer.enable()``), admission
 records ``serve.admit`` with its phases and each step records
-``serve.step`` with the slots it decoded and the tokens they gave
-(ARCHITECTURE.md, "Tracing"); off, each span costs one attribute check.
+``serve.step`` with the slots it decoded and the tokens they gave, and
+its ``serve.step.interval`` the KV pages the decode kernel walks against
+the table pages (ARCHITECTURE.md, "Tracing"); off, each span costs one
+attribute check.
 
 Token semantics match the serial `ServeEngine.generate` exactly in both
 modes: the first emitted token is the greedy pick from the prefill logits;
@@ -430,12 +432,24 @@ class ContinuousBatchingScheduler:
                 n_drawn += delta
         return n_drawn
 
+    def _kv_pages(self) -> Dict[str, int]:
+        """Pages the paged decode kernel walks per tick at an interval's
+        start, summed over the active slots (`kv_pages_live`: each slot's
+        live range), against the table pages they hold (`kv_pages_table`)."""
+        layout = self.decoder.layout
+        live = [layout.pages_for(int(self._pos_host[slot]) + 1)
+                for slot, row in enumerate(self._table) if row is not None]
+        return {"kv_pages_live": sum(live),
+                "kv_pages_table": len(live) * layout.n_pages_seq}
+
     def _step_paged(self, sp) -> List[FinishedRequest]:
         done: List[FinishedRequest] = []
         tr = self._tracer
         with tr.span("serve.step.grow") as grow:
             grow.set(drawn=self._grow_pages())
-        with tr.span("serve.step.interval"):
+        with tr.span("serve.step.interval") as interval:
+            if tr.enabled:
+                interval.set(**self._kv_pages())
             out_buf, done_mask, pos = self.decoder.run_interval(self._page_table)
         self._pos_host[:] = pos
         self.ticks += self.decoder.sync_interval

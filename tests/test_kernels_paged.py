@@ -116,6 +116,111 @@ class TestPagedKernelParity:
         np.testing.assert_array_equal(base, got)
 
 
+# -- the bounded walk: only each slot's live pages ------------------------------
+# hd 128 takes the block walk with the kernel's own page copies; hd 64 the
+# per-page grid clamped to the live range (Mosaic slices pages out of the
+# pool only at head widths that are multiples of 128).
+WALK_HD = [pytest.param(128, id="block-walk"), pytest.param(64, id="page-grid")]
+PAGE = 8
+
+
+def _ppb(hd, n):
+    from repro.kernels.paged_decode_attention import pages_per_block
+
+    return pages_per_block(PAGE, hd, 4, n)
+
+
+def _pallas(q, kp, vp, tbl, pos, window=0):
+    return np.asarray(ops.paged_decode_attention(q, kp, vp, tbl, jnp.asarray(pos, jnp.int32),
+                                                 window=window, impl="pallas"))
+
+
+def _oracle(q, kp, vp, tbl, pos, window=0):
+    return np.asarray(ref.paged_decode_attention(q, kp, vp, tbl, jnp.asarray(pos, jnp.int32),
+                                                 window=window))
+
+
+class TestBoundedWalk:
+    @pytest.mark.parametrize("hd", WALK_HD)
+    @pytest.mark.parametrize("extra", [pytest.param(5, id="n-not-multiple-of-ppb"),
+                                       pytest.param(0, id="n-multiple-of-ppb")])
+    def test_positions_at_page_and_block_edges_match_the_oracle(self, hd, extra):
+        """One batch with pos at 0, page-1, page, ppb*page-1, ppb*page and
+        n*page-1: every edge of a page and of a block of pages."""
+        ppb = _ppb(hd, 64)
+        n = 2 * ppb + extra
+        assert _ppb(hd, n) == ppb and (n % ppb != 0) == (extra != 0)
+        pos = [0, PAGE - 1, PAGE, ppb * PAGE - 1, ppb * PAGE, n * PAGE - 1]
+        q, kp, vp, tbl = _pool_case(10 + extra, B=len(pos), H=4, KV=2, hd=hd, page=PAGE,
+                                    n_pages=n, pool_pages=len(pos) * n + 1, dtype=jnp.float32)
+        np.testing.assert_allclose(_pallas(q, kp, vp, tbl, pos), _oracle(q, kp, vp, tbl, pos),
+                                   atol=1e-5)
+
+    @pytest.mark.parametrize("hd", WALK_HD)
+    def test_table_entries_past_the_live_range_are_never_read(self, hd):
+        """Entries past each slot's last live page point at other slots'
+        pages, or at pages outside every table holding NaN; the output is
+        bit-for-bit what the clean table gives."""
+        B, n = 4, 12
+        q, kp, vp, tbl = _pool_case(11, B=B, H=4, KV=1, hd=hd, page=PAGE, n_pages=n,
+                                    pool_pages=B * n + 3, dtype=jnp.float32)
+        pos = [3, 17, 40, 90]
+        used = set(np.asarray(tbl).ravel().tolist())
+        spare = [p for p in range(1, B * n + 3) if p not in used]
+        kp = kp.at[jnp.asarray(spare)].set(jnp.nan)
+        vp = vp.at[jnp.asarray(spare)].set(jnp.nan)
+        clean = _pallas(q, kp, vp, tbl, pos)
+        dirty = np.asarray(tbl).copy()
+        for b, p in enumerate(pos):
+            for i in range(p // PAGE + 1, n):
+                dirty[b, i] = spare[i % len(spare)] if i % 2 else dirty[(b + 1) % B, i]
+        got = _pallas(q, kp, vp, jnp.asarray(dirty), pos)
+        np.testing.assert_array_equal(got, clean)
+        np.testing.assert_allclose(clean, _oracle(q, kp, vp, tbl, pos), atol=1e-5)
+
+    @pytest.mark.parametrize("hd", WALK_HD)
+    def test_negative_pos_walks_nothing_and_returns_zeros(self, hd):
+        """A slot at pos -1 (inactive or frozen in the decode step) gives
+        exact zeros; its neighbours are untouched by it."""
+        B, n = 3, 6
+        q, kp, vp, tbl = _pool_case(12, B=B, H=4, KV=2, hd=hd, page=PAGE, n_pages=n,
+                                    pool_pages=B * n + 1, dtype=jnp.float32)
+        got = _pallas(q, kp, vp, tbl, [20, -1, 45])
+        base = _pallas(q, kp, vp, tbl, [20, 7, 45])
+        np.testing.assert_array_equal(got[1], np.zeros_like(got[1]))
+        np.testing.assert_array_equal(got[[0, 2]], base[[0, 2]])
+        np.testing.assert_allclose(got[[0, 2]], _oracle(q, kp, vp, tbl, [20, 7, 45])[[0, 2]],
+                                   atol=1e-5)
+
+    @pytest.mark.parametrize("hd", WALK_HD)
+    def test_window_starts_the_walk_past_page_zero(self, hd):
+        """With a window, the walk starts at the page holding pos-window+1:
+        pages wholly before it hold NaN and the output still matches the
+        oracle over the window."""
+        B, n, w = 3, 20, 12
+        q, kp, vp, tbl = _pool_case(13, B=B, H=4, KV=1, hd=hd, page=PAGE, n_pages=n,
+                                    pool_pages=B * n + 1, dtype=jnp.float32)
+        t = 1 + np.random.default_rng(13).permutation(B * n).reshape(B, n).astype(np.int32)
+        tbl = jnp.asarray(t)  # no page in two rows: a stale page is no one's live page
+        pos = [5, 77, 159]  # first page 0, 8 and 18
+        want = _oracle(q, kp, vp, tbl, pos, window=w)
+        stale = [int(t[b, i]) for b, p in enumerate(pos) for i in range(max(0, p - w + 1) // PAGE)]
+        assert stale  # some slot starts past page 0
+        kp = kp.at[jnp.asarray(stale)].set(jnp.nan)
+        vp = vp.at[jnp.asarray(stale)].set(jnp.nan)
+        np.testing.assert_allclose(_pallas(q, kp, vp, tbl, pos, window=w), want, atol=1e-5)
+
+    @pytest.mark.parametrize("hd", WALK_HD)
+    def test_bf16_pool_matches_the_oracle(self, hd):
+        B, n = 4, 40
+        q, kp, vp, tbl = _pool_case(14, B=B, H=8, KV=2, hd=hd, page=16, n_pages=n,
+                                    pool_pages=B * n + 1, dtype=jnp.bfloat16)
+        pos = [0, 255, 256, 639]
+        np.testing.assert_allclose(
+            _pallas(q, kp, vp, tbl, pos).astype(np.float32),
+            _oracle(q, kp, vp, tbl, pos).astype(np.float32), atol=5e-2)
+
+
 class TestPagedLayout:
     def test_ring_when_window_fits(self, ):
         from repro.configs import get_config

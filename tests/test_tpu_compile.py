@@ -1,7 +1,9 @@
 """Compile the serve path's decode kernels for a TPU v5e that is described,
 not attached, at gemma3-1b widths (H=4, KV=1, hd=256, bfloat16, page 16,
-B=8). Mosaic refuses here what it would refuse on the chip (unaligned
-blocks, SMEM operand shapes, VMEM overuse), so a kernel change that only
+B=8), the paged kernel also at the shapes of the chip benchmark's
+granite-20b.chat cell and at head widths that take its per-page grid.
+Mosaic refuses here what it would refuse on the chip (unaligned blocks,
+SMEM operand shapes, VMEM overuse), so a kernel change that only
 interpret mode accepts fails in this file.
 
 The topology is described inside a fixture: the TPU library admits one
@@ -84,5 +86,25 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip, window):
         _sds(one_chip, (P, PAGE, KV, HD), BF16),
         _sds(one_chip, (B, N_PAGES), jnp.int32),
         _sds(one_chip, (B,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "b, h, kv, hd, n, pool",
+    [
+        pytest.param(48, 48, 1, 128, 144, 6913, id="granite-20b.chat"),
+        pytest.param(8, 16, 4, 128, 44, 353, id="gqa-kv4-block-walk"),
+        pytest.param(8, 64, 8, 160, 44, 353, id="hd160-page-grid"),
+    ],
+)
+def test_paged_decode_attention_compiles_at_serving_shapes(one_chip, b, h, kv, hd, n, pool):
+    fn = jax.jit(lambda q, k, v, t, p: paged_decode_attention(q, k, v, t, p, interpret=False))
+    compiled = fn.lower(
+        _sds(one_chip, (b, h, hd), BF16),
+        _sds(one_chip, (pool, PAGE, kv, hd), BF16),
+        _sds(one_chip, (pool, PAGE, kv, hd), BF16),
+        _sds(one_chip, (b, n), jnp.int32),
+        _sds(one_chip, (b,), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -299,3 +299,32 @@ def test_tokens_are_identical_with_the_tracer_on_and_off(bundle, kv_mode):
         out.append({rid: (f.tokens, f.finish_reason) for rid, f in results.items()})
         assert bool(sched.decoder.rt.tracer.drain().spans) is on
     assert out[0] == out[1]
+
+
+def test_interval_counts_live_and_table_kv_pages_only_when_tracing(bundle, monkeypatch):
+    """On, every `serve.step.interval` carries the pages the decode kernel
+    walks (`kv_pages_live`) against the active slots' table pages
+    (`kv_pages_table`); off, the count is never taken."""
+    cfg = bundle[0]
+    sched = _paged(bundle)
+    tr = sched.decoder.rt.tracer
+    reqs = _requests(cfg, 6, seed=11)
+    tr.enable()
+    _serve_in_order(sched, reqs)
+    spans, _ = tr.drain()
+    n_table = sched.decoder.layout.n_pages_seq
+    intervals = _named(spans, "serve.step.interval")
+    assert intervals
+    for s in intervals:
+        live, table = s.attrs["kv_pages_live"], s.attrs["kv_pages_table"]
+        assert 0 < live <= table and table % n_table == 0
+    assert any(s.attrs["kv_pages_live"] < s.attrs["kv_pages_table"] for s in intervals)
+
+    off = _paged(bundle)
+
+    def never(self):
+        raise AssertionError("page counts taken with the tracer off")
+
+    monkeypatch.setattr(ContinuousBatchingScheduler, "_kv_pages", never)
+    assert len(_serve_in_order(off, reqs)) == len(reqs)
+    assert off.decoder.rt.tracer.drain().spans == []
