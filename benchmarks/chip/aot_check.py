@@ -44,7 +44,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     jax.config.update("jax_enable_compilation_cache", False)
     import harness
-    import refmodel
     import traffic_gen
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
@@ -59,7 +58,8 @@ def main(argv=None) -> int:
     def spec(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
 
-    pspec = jax.tree_util.tree_map(spec, jax.eval_shape(lambda: refmodel.make_weights(prog, 0)))
+    shapes = jax.eval_shape(lambda: cell.reference.make_weights(prog, 0))
+    pspec = jax.tree_util.tree_map(spec, shapes)
     wbytes = sum(np.prod(x.shape) * x.dtype.itemsize for x in jax.tree_util.tree_leaves(pspec))
     with Runtime("jaxdev") as rt:
         sched = harness.build_scheduler(cell, None, rt)

@@ -11,11 +11,28 @@ A cell is found by name: ``BENCHMARK.json`` names its configuration and
 traffic, ``configs/<config>.json`` and ``traffic/<traffic>.json`` hold them,
 and ``cells/<cell>.json`` holds the serving parameters (rate, batch, the
 traffic served before the window, the correctness limit). Each metric is read by ``metrics/<metric>.py``.
+
+A configuration names its plain reference with ``"reference": "<module>"``
+(default ``refmodel``): the file ``<module>.py`` beside this one, loaded by
+path (`load_reference`). The harness, the tools and the work counts reach the
+reference only through `Cell.reference`. A reference module provides:
+
+* ``make_weights(program, seed)``: weights from the seed, made on the
+  device, in the tree layout the program under test takes;
+* ``served_gaps(program, params, prompt, served, *, length, pad_rows,
+  controls)``: ``{"program": widest gap of the served tokens, <control>:
+  widest gap of the tokens each control puts first}`` (`compare`);
+* ``QUANTIZERS``: a dict whose keys are the controls it can compute;
+* optionally the counts `work` reads, each taking the program dict:
+  ``matmul_params(p)``, ``attn_flops(p, ctx)``, ``attn_bytes(p, ctx)`` and
+  ``decode_flops(p, ctx)``. Without them the readers built on them read
+  nothing.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -24,6 +41,7 @@ import shutil
 import sys
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -57,19 +75,42 @@ class Cell:
     def program(self) -> dict:
         return self.config["program"]
 
+    @functools.cached_property
+    def reference(self) -> ModuleType:
+        """The configuration's reference module (module docstring)."""
+        return load_reference(self.config.get("reference", "refmodel"))
+
 
 def load_cell(name: str, spec: Optional[dict] = None) -> Cell:
     spec = spec or bench()
     entry = next((w for w in spec["workloads"] if w["name"] == name), None)
     if entry is None:
         raise SystemExit(f"unknown workload {name!r}")
-    return Cell(
+    cell = Cell(
         name=name,
         chips=entry["chips"],
         config=json.loads((HERE / "configs" / f"{entry['config']}.json").read_text()),
         traffic=traffic_gen.load(entry["traffic"]),
         serve=json.loads((HERE / "cells" / f"{name}.json").read_text()),
     )
+    cell.reference  # a reference named without its file stops here
+    return cell
+
+
+def load_reference(name: str) -> ModuleType:
+    """The reference module ``<name>.py`` beside this file, loaded once per
+    process and file (its jitted functions then compile once)."""
+    path = HERE / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"reference {name!r}: no file {path}")
+    key = f"reference_{name}"
+    mod = sys.modules.get(key)
+    if mod is None or Path(mod.__file__) != path:
+        mod_spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(mod_spec)
+        sys.modules[key] = mod  # before it runs, as an import would
+        mod_spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_names(cell: str, trace: bool, spec: Optional[dict] = None) -> List[dict]:
@@ -360,12 +401,10 @@ def sample(finished: Dict[str, tuple], t_open: float, t_close: float, seed: int,
 def compare(cell: Cell, params, picked, controls=()) -> dict:
     """Widest reference gap over the picked requests' served tokens, and
     for each control the widest gap of the tokens it puts first."""
-    import refmodel
-
     s = cell.serve
     out: Dict[str, float] = {}
     for fin in picked:
-        g = refmodel.served_gaps(
+        g = cell.reference.served_gaps(
             cell.program, params, fin.prompt, fin.tokens,
             length=s["max_len"], pad_rows=cell.traffic["output"]["max"],
             controls=controls,
@@ -421,18 +460,17 @@ def serve_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if on_tpu:
         enable_cache()
 
-    import refmodel
     from repro.core.runtime import Runtime
     from repro.serve.scheduler import Request
 
     prog = cell.program
-    params = refmodel.make_weights(prog, seed)
+    params = cell.reference.make_weights(prog, seed)
     jax.block_until_ready(params)
     s = cell.serve
     pre = s["pre_seconds"]
     items = traffic_gen.schedule(
-        cell.traffic, rate=s["rate"], span=pre + seconds + 2.0, pre=pre,
-        seed=seed, vocab=prog["vocab_size"],
+        cell.traffic, rate=s["rate"], pre=pre, seconds=seconds, seed=seed,
+        vocab=prog["vocab_size"],
     )
     compiles: list = []
     _listen_compiles(compiles)
@@ -468,8 +506,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
              control: Optional[str] = None) -> int:
     """One run of one cell, as the benchmark command makes it: set-up, the
     window, the check. Prints the result line last on stdout. With
-    `control` (a `refmodel.QUANTIZERS` key), the reference at that lower
-    precision is judged in the program's place, by the same decision."""
+    `control` (a key of the reference's `QUANTIZERS`), the reference at
+    that lower precision is judged in the program's place, by the same
+    decision."""
     served = serve_cell(cell, seed, seconds, trace, devices=devices, t_start=t_start)
     run, finished, info = served.run, served.finished, served.info
     memory_peak, n_setup, n_window = served.memory_peak, served.compiles_setup, served.compiles_window

@@ -1,6 +1,7 @@
 """Model operations of the window's decode steps (the held matmul weights
 and attention at each slot's live context, per decoded token) over their
-host time times the chip's bf16 peak."""
+host time times the chip's bf16 peak. Nothing to read where the cell's
+reference counts no decode operations."""
 import work
 
 
@@ -9,6 +10,7 @@ def read(run):
     t = sum(s.t1 - s.t0 for s in steps)
     if not t or not run.peaks:
         return None
-    p = run.cell.program
-    flops = sum(work.decode_flops(p, c) for s in steps for c in work.contexts(s.slots))
-    return 100.0 * flops / (t * run.peaks["bf16_flops_per_s"])
+    per_token = [work.decode_flops(run.cell, c) for s in steps for c in work.contexts(s.slots)]
+    if None in per_token:
+        return None
+    return 100.0 * sum(per_token) / (t * run.peaks["bf16_flops_per_s"])

@@ -15,6 +15,9 @@ under ``jax.default_matmul_precision("highest")``.
 `QUANTIZERS` make the controls: the same reference with every matrix
 rounded to a lower precision (int8 per output channel, float8 e4m3, or
 bfloat16 for a float32 configuration).
+
+`matmul_params`, `attn_flops`, `attn_bytes` and `decode_flops` count the
+work of one decoded token of this block, for the benchmark's `work` module.
 """
 from __future__ import annotations
 
@@ -71,6 +74,39 @@ def make_weights(p: dict, seed: int):
 
     key = jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF), (seed >> 31) & 0x7FFFFFFF)
     return build(key)
+
+
+# -- work counts -----------------------------------------------------------------
+
+BF16 = 2  # bytes per element of the served weights, activations and cache
+
+
+def matmul_params(p: dict) -> int:
+    """Weights a decode token multiplies by: every layer's projections and
+    MLP, and the output head (the embedding is a gather)."""
+    m = dims(p)
+    d, H, KV, hd, f = m["d"], m["H"], m["KV"], m["hd"], m["f"]
+    attn = d * H * hd + 2 * d * KV * hd + H * hd * d
+    mlp = (3 if m["gated"] else 2) * d * f
+    return m["L"] * (attn + mlp) + d * m["V"]
+
+
+def attn_flops(p: dict, ctx: int) -> int:
+    """Single-token attention over `ctx` positions, all layers: QK^T and PV."""
+    m = dims(p)
+    return m["L"] * 4 * m["H"] * m["hd"] * ctx
+
+
+def attn_bytes(p: dict, ctx: int) -> int:
+    """Single-token attention, all layers: K and V of `ctx` positions, the
+    query in and the output out."""
+    m = dims(p)
+    return m["L"] * BF16 * (2 * ctx * m["KV"] * m["hd"] + 2 * m["H"] * m["hd"])
+
+
+def decode_flops(p: dict, ctx: int) -> int:
+    """Model operations of one decoded token at context `ctx`."""
+    return 2 * matmul_params(p) + attn_flops(p, ctx)
 
 
 # -- quantisers for the control ---------------------------------------------

@@ -43,7 +43,6 @@ def main(argv=None) -> int:
     import numpy as np
 
     import harness
-    import refmodel
     import traffic_gen
     from repro.core.runtime import Runtime
     from repro.serve.scheduler import Request
@@ -52,7 +51,7 @@ def main(argv=None) -> int:
     devices = harness.check_device(cell.chips)
     harness.enable_cache()
     prog = cell.program
-    params = refmodel.make_weights(prog, args.seed)
+    params = cell.reference.make_weights(prog, args.seed)
     pre = cell.serve["pre_seconds"]
     with Runtime("jaxdev") as rt:
         sched = harness.build_scheduler(cell, params, rt)
@@ -60,8 +59,9 @@ def main(argv=None) -> int:
         print(f"set-up {time.perf_counter() - T_START:.1f}s", flush=True)
         for k, rate in enumerate(float(r) for r in args.rates.split(",")):
             sched.prefix.reset()
-            items = traffic_gen.schedule(cell.traffic, rate=rate, span=pre + args.seconds,
-                                         pre=pre, seed=args.seed + k, vocab=prog["vocab_size"])
+            items = traffic_gen.schedule(cell.traffic, rate=rate, pre=pre,
+                                         seconds=args.seconds, seed=args.seed + k,
+                                         vocab=prog["vocab_size"])
             d = harness.Driver(sched, Request, items)
             compiles: list = []
             harness._listen_compiles(compiles)

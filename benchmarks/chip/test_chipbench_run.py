@@ -2,9 +2,10 @@
 a test run holds: the harness drives the program's paged prefix-cached
 scheduler through set-up, traffic and the window, then compares the served
 tokens with the float32 reference. A sound run is correct; the float8
-control, judged in the program's place, is not; and each fault planted
+control, judged in the program's place, is not; each fault planted
 under the timed path (a token altered where it is produced, a decode
-interval that returns the KV state unchanged) turns `correct` false."""
+interval that returns the KV state unchanged) turns `correct` false; and
+under documents asked again the radix cache hits as the asks predict."""
 from __future__ import annotations
 
 import json
@@ -121,6 +122,78 @@ def test_a_fault_under_the_timed_path_is_not_correct(name, fault, monkeypatch):
     line = run_line(tiny_cell(CELLS[name]))
     assert line["correct"] is False
     assert line["checks"]["logit_gap"]["value"] > LIMIT
+
+
+#: documents asked three or four times (one not a whole number of 16-token
+#: pages), questions of a page or more; no traffic before the window, and a
+#: `max_len` whose default pool (the slots' worst case, 4 x 32 + 1 pages)
+#: holds every page the run writes (10 documents of 2 full pages, 35 asks
+#: of at most 2 more, 4 slots' reservations of at most 5), so that the ask
+#: counts alone decide which asks hit
+DOC_QA = {"shared": {"documents": [32, 41], "asks": [3, 4], "questions": [16, 21]},
+          "output": {"dist": "uniform", "min": 9, "max": 16}}
+
+
+def test_shared_documents_hit_as_their_asks_predict(monkeypatch, capsys):
+    """A tiny cell under document traffic, through `run_cell`: correct, no
+    compile in the window, and the radix cache's hit share is what the ask
+    counts predict: an ask hits its whole document where an earlier ask of
+    it has finished (asks - 1 of asks, where they come one after another)."""
+    import jax
+
+    import traffic_gen
+
+    cell = tiny_cell(False)
+    cell.traffic = DOC_QA
+    # fewer requests finish in the window than under chat: prompts are longer
+    cell.serve = dict(cell.serve, pre_seconds=0.0, max_len=512, sample_tokens=80,
+                      sample_requests=8)
+    kept = {}
+
+    def serve_and_keep(*args, **kw):
+        kept["served"] = serve_cell(*args, **kw)
+        return kept["served"]
+
+    serve_cell = harness.serve_cell
+    monkeypatch.setattr(harness, "serve_cell", serve_and_keep)
+    lines = []
+    metrics = [{"name": "prefix_hit_share", "unit": "%"},
+               {"name": "output_tok_s", "unit": "tokens/s"}]
+    harness.run_cell(cell, 17, SECONDS, False, metrics=metrics, devices=jax.devices(),
+                     t_start=time.perf_counter(), emit=lines.append)
+    line = json.loads(lines[-1])
+    assert line["correct"] is True
+    served = kept["served"]
+    assert served.compiles_window == 0
+    assert "compiles in window 0," in capsys.readouterr().err
+    run = served.run
+    assert run.prefix_close["evictions"] == run.prefix_open["evictions"]
+
+    items = traffic_gen.schedule(DOC_QA, rate=cell.serve["rate"], pre=0.0, seconds=SECONDS,
+                                 seed=17, vocab=cell.program["vocab_size"])
+    prompts = {it.rid: it.prompt for it in items}
+    asks = {}
+    for rid, p in prompts.items():
+        asks.setdefault(p[0], []).append(rid)
+    doc_len = {}  # an ask's document: the prefix all asks of it share
+    for rids in asks.values():
+        d = min(next(i for i, (x, y) in enumerate(zip(prompts[rids[0]], prompts[r])) if x != y)
+                for r in rids[1:])
+        doc_len.update({r: d for r in rids})
+    admitted = sorted((r.admit_start, rid) for rid, r in run.requests.items()
+                      if r.admit_start is not None)
+    hit = n_hits = queried = 0
+    for t, rid in admitted:
+        queried += len(prompts[rid])
+        first = prompts[rid][0]
+        if any(run.requests[o].finished is not None and run.requests[o].finished <= t
+               for o in asks[first] if o != rid):
+            hit += doc_len[rid]
+            n_hits += 1
+    assert n_hits >= 3
+    page = 16
+    assert line["metrics"]["prefix_hit_share"]["value"] == pytest.approx(
+        100.0 * hit / queried, abs=100.0 * page * n_hits / queried)
 
 
 def _bench_cmd(cwd, workload="granite-20b.chat"):

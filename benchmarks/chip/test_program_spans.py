@@ -130,7 +130,6 @@ def test_a_served_window_records_spans_the_readers_and_profiler_read(tmp_path):
     import jax
 
     import harness
-    import refmodel
     import traffic_gen
     from repro.core.runtime import Runtime
     from repro.serve.scheduler import Request
@@ -143,8 +142,8 @@ def test_a_served_window_records_spans_the_readers_and_profiler_read(tmp_path):
                "output": {"dist": "uniform", "min": 6, "max": 12}}
     cell = harness.Cell(name="tiny", chips=1, config={"name": "tiny", "program": prog},
                         traffic=traffic, serve={"max_batch": 3, "max_len": 48})
-    params = refmodel.make_weights(prog, 5)
-    items = traffic_gen.schedule(traffic, rate=6.0, span=3.0, pre=0.0, seed=5,
+    params = cell.reference.make_weights(prog, 5)
+    items = traffic_gen.schedule(traffic, rate=6.0, pre=0.0, seconds=3.0, seed=5,
                                  vocab=prog["vocab_size"])
     with Runtime("jaxdev") as rt:
         sched = harness.build_scheduler(cell, params, rt)

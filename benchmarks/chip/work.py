@@ -5,40 +5,40 @@ reads the keys and values of the live positions only, and its operations
 are taken at each slot's live context. A kernel that walks every page of
 `max_len`, or copies the pool, does more than this and reads as a lower
 share of its roofline.
+
+The counts belong to the block, so each comes from the cell's reference
+module (its function of the same name, taking the program dict). Where the
+reference defines no such function the count is None, and a reader built
+on it reads nothing: a dense count never stands in for another block.
 """
 from __future__ import annotations
 
-from refmodel import dims
-
-BF16 = 2  # bytes per element of the served weights, activations and cache
+from typing import Optional
 
 
-def matmul_params(p: dict) -> int:
-    """Weights a decode token multiplies by: every layer's projections and
-    MLP, and the output head (the embedding is a gather)."""
-    m = dims(p)
-    d, H, KV, hd, f = m["d"], m["H"], m["KV"], m["hd"], m["f"]
-    attn = d * H * hd + 2 * d * KV * hd + H * hd * d
-    mlp = (3 if m["gated"] else 2) * d * f
-    return m["L"] * (attn + mlp) + d * m["V"]
+def _count(cell, name: str, *args) -> Optional[int]:
+    fn = getattr(cell.reference, name, None)
+    return None if fn is None else fn(cell.program, *args)
 
 
-def attn_flops(p: dict, ctx: int) -> int:
-    """Single-token attention over `ctx` positions, all layers: QK^T and PV."""
-    m = dims(p)
-    return m["L"] * 4 * m["H"] * m["hd"] * ctx
+def matmul_params(cell) -> Optional[int]:
+    """Weights a decode token multiplies by."""
+    return _count(cell, "matmul_params")
 
 
-def attn_bytes(p: dict, ctx: int) -> int:
-    """Single-token attention, all layers: K and V of `ctx` positions, the
-    query in and the output out."""
-    m = dims(p)
-    return m["L"] * BF16 * (2 * ctx * m["KV"] * m["hd"] + 2 * m["H"] * m["hd"])
+def attn_flops(cell, ctx: int) -> Optional[int]:
+    """Single-token attention over `ctx` positions, all layers."""
+    return _count(cell, "attn_flops", ctx)
 
 
-def decode_flops(p: dict, ctx: int) -> int:
+def attn_bytes(cell, ctx: int) -> Optional[int]:
+    """Bytes single-token attention over `ctx` positions moves, all layers."""
+    return _count(cell, "attn_bytes", ctx)
+
+
+def decode_flops(cell, ctx: int) -> Optional[int]:
     """Model operations of one decoded token at context `ctx`."""
-    return 2 * matmul_params(p) + attn_flops(p, ctx)
+    return _count(cell, "decode_flops", ctx)
 
 
 def contexts(slots):
