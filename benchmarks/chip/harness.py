@@ -27,6 +27,20 @@ reference only through `Cell.reference`. A reference module provides:
   ``matmul_params(p)``, ``attn_flops(p, ctx)``, ``attn_bytes(p, ctx)`` and
   ``decode_flops(p, ctx)``. Without them the readers built on them read
   nothing.
+
+A metric reader ``metrics/<metric>.py`` defines ``read(run)``, returning a
+number, or None when it finds nothing to read. Besides the benchmark's own
+records on the `Run`, a reader of a traced run may read the program's spans,
+``run.spans``: the `repro.core.tracing.Span` records (``name``, ``id``,
+``parent_id``, ``t0_ns``, ``t1_ns`` on the clock of `time.perf_counter_ns`,
+and ``attrs``, the program's counters) of the spans that opened and closed
+inside the profiled part of the window. The program's tracer is on only
+there, so ``run.spans`` is empty on untraced runs, and it is empty too where
+the tracer's ring dropped any span (``run.spans_dropped``): a reader never
+sees part of a window. A span or attribute a reader does not find means
+nothing to read. So a configuration whose program records spans of its own
+brings a reader of them as one new file and one new ``per_layer`` entry,
+and edits no file that is here.
 """
 from __future__ import annotations
 
@@ -173,8 +187,11 @@ class Run:
     admits: List[tuple]  # (t0, t1) of every try_admit that seated a request
     prefix_open: dict
     prefix_close: dict
-    trace: Optional[dict] = None  # trace_reduce.summarize output
+    trace: Optional[dict] = None  # program_spans.summarize output
     trace_span: Optional[tuple] = None  # host-clock (t0, t1) traced
+    #: the program's spans inside `trace_span`, none if any was dropped
+    spans: list = dataclasses.field(default_factory=list)
+    spans_dropped: int = 0  # spans the program's tracer ring dropped
 
     @property
     def window_s(self) -> float:
@@ -484,7 +501,7 @@ def serve_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         setup_s = clock() - t_start
         n_setup = len(compiles)
         prefix_open = dict(sched.prefix.stats())
-        tracer = _Tracer(trace, t_open)
+        tracer = _Tracer(trace, t_open, rt.tracer)
         driver.run(t_open, t_close, hook=tracer.hook)
         tracer.stop()
         prefix_close = dict(sched.prefix.stats())
@@ -494,7 +511,8 @@ def serve_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                   t_close=t_close, requests=driver.requests, steps=driver.steps,
                   admits=driver.admits, prefix_open=prefix_open,
                   prefix_close=prefix_close, trace=tracer.summary(),
-                  trace_span=tracer.span)
+                  trace_span=tracer.span, spans=tracer.spans,
+                  spans_dropped=tracer.dropped)
         finished = driver.finished
         del sched, driver
     gc.collect()
@@ -560,13 +578,18 @@ def _listen_compiles(sink: list) -> None:
 
 
 class _Tracer:
-    """Profiles the first TRACE_SECONDS of the window (traced runs only)."""
+    """Profiles the first TRACE_SECONDS of the window (traced runs only),
+    with the program's tracer (`repro.core.tracing.Tracer`) on for as long
+    as the profiler runs."""
 
-    def __init__(self, on: bool, t_open: float):
+    def __init__(self, on: bool, t_open: float, program):
         self.on = on
         self.t_open = t_open
+        self.program = program
         self.state = "idle"
         self.span = None
+        self.spans: list = []
+        self.dropped = 0
         self.dir = OUT_DIR / "trace"
 
     def hook(self, now: float) -> None:
@@ -577,6 +600,7 @@ class _Tracer:
         if self.state == "idle" and now >= self.t_open:
             shutil.rmtree(self.dir, ignore_errors=True)
             jax.profiler.start_trace(str(self.dir))
+            self.program.enable()
             self.t0 = clock()
             self.state = "on"
         elif self.state == "on" and now - self.t0 >= TRACE_SECONDS:
@@ -588,16 +612,24 @@ class _Tracer:
         if self.state != "on":
             return
         t1 = clock()
+        self.program.disable()
         jax.profiler.stop_trace()
         self.span = (self.t0, t1)
         self.state = "done"
+        spans, self.dropped = self.program.drain()
+        if self.dropped:
+            print(f"the program's tracer dropped {self.dropped} spans: the span "
+                  "readers read nothing", file=sys.stderr)
+            return
+        a, b = self.t0 * 1e9, t1 * 1e9
+        self.spans = [s for s in spans if a <= s.t0_ns and s.t1_ns <= b]
 
     def summary(self) -> Optional[dict]:
         if self.state != "done":
             return None
-        import trace_reduce
+        import program_spans
 
         try:
-            return trace_reduce.summarize(self.dir)
+            return program_spans.summarize(self.dir)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)

@@ -67,7 +67,8 @@ def extract_program(path: str) -> List[Named]:
 
 
 def summarize(trace_dir) -> dict:
-    """`trace_reduce.summarize`, with idle gaps named by program span."""
+    """The reduction of the trace under `trace_dir` (`trace_reduce.reduce`),
+    with idle gaps named by program span."""
     path = trace_reduce.find_xplane(trace_dir)
     device_ops, host = trace_reduce.extract(path)
     return trace_reduce.reduce(device_ops, label(host, extract_program(path)))

@@ -69,7 +69,8 @@ def main(argv=None) -> int:
             t_close = t_open + args.seconds
             d.run(t_open, t_open)
             n0 = len(compiles)
-            tracer = harness._Tracer(bool(args.describe_trace) and k == 0, t_open)
+            tracer = harness._Tracer(bool(args.describe_trace) and k == 0, t_open,
+                                     rt.tracer)
             d.run(t_open, t_close, hook=tracer.hook)
             tracer.stop()
             if tracer.state == "done":

@@ -5,7 +5,10 @@ tokens with the float32 reference. A sound run is correct; the float8
 control, judged in the program's place, is not; each fault planted
 under the timed path (a token altered where it is produced, a decode
 interval that returns the KV state unchanged) turns `correct` false; and
-under documents asked again the radix cache hits as the asks predict."""
+under documents asked again the radix cache hits as the asks predict. A
+traced run hands the program's spans to the metric readers, an untraced one
+never turns the program's tracer on, and a configuration brings a reader of
+its own spans as a new file."""
 from __future__ import annotations
 
 import json
@@ -98,6 +101,103 @@ def test_run_line_names_its_checks_last():
     assert line["device"]["platform"] == "cpu" and line["attempted"] > 0
 
 
+def run_keeping(monkeypatch, tmp_path, cell, seed, trace, metrics, seconds=SECONDS):
+    """`run_cell` as the benchmark command makes it, keeping what it served;
+    traces go under `tmp_path`."""
+    import jax
+
+    kept = {}
+    serve_cell = harness.serve_cell
+
+    def serve_and_keep(*args, **kw):
+        kept["served"] = serve_cell(*args, **kw)
+        return kept["served"]
+
+    monkeypatch.setattr(harness, "serve_cell", serve_and_keep)
+    monkeypatch.setattr(harness, "OUT_DIR", tmp_path)
+    lines = []
+    harness.run_cell(cell, seed, seconds, trace, metrics=metrics, devices=jax.devices(),
+                     t_start=time.perf_counter(), emit=lines.append)
+    return json.loads(lines[-1]), kept["served"]
+
+
+SPAN_METRICS = {"prefill_ms.p50", "admit_host_ms.p50", "step_host_ms.p50"}
+
+
+def test_a_traced_run_hands_the_programs_spans_to_the_readers(monkeypatch, tmp_path):
+    """The program's spans inside the profiled part become `Run.spans`, the
+    span readers read them, and idle gaps are named by program span (a CPU
+    trace has no device plane: ops that leave the device idle for 1 us at
+    the middle of each benchmark span stand in for the device's)."""
+    import program_spans
+    import trace_reduce
+
+    extract = trace_reduce.extract
+
+    def with_device_ops(path):
+        _, host = extract(path)
+        mids = [(a + b) // 2 for a, b, _ in sorted(host)]
+        edges = ([min(a for a, _, _ in host) - 1000] + mids
+                 + [max(b for _, b, _ in host) + 1000])
+        ops = [(x + 500, y - 500, "op") for x, y in zip(edges, edges[1:])]
+        return {"/device:TPU:0": ops}, host
+
+    monkeypatch.setattr(trace_reduce, "extract", with_device_ops)
+    metrics = harness.metric_names("granite-20b.chat", True)
+    line, served = run_keeping(monkeypatch, tmp_path, tiny_cell(False), 19, True, metrics)
+    run = served.run
+    a, b = run.trace_span
+    assert run.spans and run.spans_dropped == 0
+    assert all(a * 1e9 <= s.t0_ns <= s.t1_ns <= b * 1e9 for s in run.spans)
+    assert {"serve.admit", "serve.admit.prefill", "serve.step",
+            "serve.step.interval"} <= {s.name for s in run.spans}
+    for m in SPAN_METRICS:
+        assert line["metrics"][m]["value"] > 0
+    gaps = [name for name, _ in line["breakdown"]["idle_gaps"]]
+    assert any(n.partition("/")[2].startswith(program_spans.PREFIXES) for n in gaps)
+    assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"]
+    assert line["correct"] is True
+
+
+def test_an_untraced_run_never_turns_the_programs_tracer_on(monkeypatch, tmp_path):
+    from repro.core.tracing import Tracer
+
+    enabled = []
+    seen = set()
+    span = Tracer.span
+
+    def watched(self, name, **attrs):
+        seen.add(self.enabled)
+        return span(self, name, **attrs)
+
+    monkeypatch.setattr(Tracer, "enable", lambda self, *a, **kw: enabled.append(a))
+    monkeypatch.setattr(Tracer, "span", watched)
+    metrics = harness.metric_names("granite-20b.chat", False)
+    line, served = run_keeping(monkeypatch, tmp_path, tiny_cell(False), 23, False, metrics)
+    assert enabled == [] and seen == {False}
+    assert served.run.spans == [] and served.run.spans_dropped == 0
+    assert served.run.trace is None and served.run.trace_span is None
+    assert list(line) == ["correct", "attempted", "failed", "metrics", "device", "checks"]
+    assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    assert set(line["metrics"]) == {m["name"] for m in metrics}
+    assert line["correct"] is True
+
+
+def test_a_ring_that_drops_spans_leaves_the_span_readers_nothing(monkeypatch, tmp_path,
+                                                                 capsys):
+    from repro.core.tracing import Tracer
+
+    enable = Tracer.enable
+    monkeypatch.setattr(Tracer, "enable", lambda self, capacity=0: enable(self, 4))
+    metrics = harness.metric_names("granite-20b.chat", True)
+    line, served = run_keeping(monkeypatch, tmp_path, tiny_cell(False), 29, True, metrics,
+                               seconds=3.0)
+    run = served.run
+    assert run.spans == [] and run.spans_dropped > 0
+    assert not SPAN_METRICS & set(line["metrics"])
+    assert f"dropped {run.spans_dropped} spans" in capsys.readouterr().err
+
+
 def _alter_tokens(self, table, _orig=None):
     out, done, pos = _orig(self, table)
     return np.where(out >= 0, (out + 1) % self.model.cfg.vocab_size, out), done, pos
@@ -134,13 +234,11 @@ DOC_QA = {"shared": {"documents": [32, 41], "asks": [3, 4], "questions": [16, 21
           "output": {"dist": "uniform", "min": 9, "max": 16}}
 
 
-def test_shared_documents_hit_as_their_asks_predict(monkeypatch, capsys):
+def test_shared_documents_hit_as_their_asks_predict(monkeypatch, tmp_path, capsys):
     """A tiny cell under document traffic, through `run_cell`: correct, no
     compile in the window, and the radix cache's hit share is what the ask
     counts predict: an ask hits its whole document where an earlier ask of
     it has finished (asks - 1 of asks, where they come one after another)."""
-    import jax
-
     import traffic_gen
 
     cell = tiny_cell(False)
@@ -148,22 +246,10 @@ def test_shared_documents_hit_as_their_asks_predict(monkeypatch, capsys):
     # fewer requests finish in the window than under chat: prompts are longer
     cell.serve = dict(cell.serve, pre_seconds=0.0, max_len=512, sample_tokens=80,
                       sample_requests=8)
-    kept = {}
-
-    def serve_and_keep(*args, **kw):
-        kept["served"] = serve_cell(*args, **kw)
-        return kept["served"]
-
-    serve_cell = harness.serve_cell
-    monkeypatch.setattr(harness, "serve_cell", serve_and_keep)
-    lines = []
     metrics = [{"name": "prefix_hit_share", "unit": "%"},
                {"name": "output_tok_s", "unit": "tokens/s"}]
-    harness.run_cell(cell, 17, SECONDS, False, metrics=metrics, devices=jax.devices(),
-                     t_start=time.perf_counter(), emit=lines.append)
-    line = json.loads(lines[-1])
+    line, served = run_keeping(monkeypatch, tmp_path, cell, 17, False, metrics)
     assert line["correct"] is True
-    served = kept["served"]
     assert served.compiles_window == 0
     assert "compiles in window 0," in capsys.readouterr().err
     run = served.run
@@ -216,3 +302,65 @@ def test_benchmark_files_alone_give_no_result(tmp_path):
                     ignore=shutil.ignore_patterns("__pycache__"))
     r = _bench_cmd(tmp_path)
     assert r.returncode != 0 and r.stdout.strip() == ""
+
+
+#: a configuration's reader of its own program's span attribute, as a
+#: configuration's PR would add it: pages the paged kernel walks per tick
+SPAN_READER = '''"""Mean over the traced window's decode intervals of the pages walked."""
+
+
+def read(run):
+    vals = [s.attrs["kv_pages_live"] for s in run.spans
+            if s.name == "serve.step.interval" and "kv_pages_live" in s.attrs]
+    return sum(vals) / len(vals) if vals else None
+'''
+
+#: what the benchmark command does after its look for a chip
+DRIVE = """
+import sys, time
+t_start = time.perf_counter()
+sys.path[:0] = sys.argv[1:3]
+import jax
+import harness
+cell = harness.load_cell("tiny.chat")
+metrics = harness.metric_names("tiny.chat", True)
+sys.exit(harness.run_cell(cell, 3, 3.0, True, metrics=metrics, devices=jax.devices()[:1],
+                          t_start=t_start))
+"""
+
+
+def test_a_configuration_brings_a_reader_of_its_own_spans_as_a_new_file(tmp_path):
+    """A copy of the benchmark gains a configuration, a traffic mix, a cell
+    and a reader of a span attribute, as new files and new entries of
+    `BENCHMARK.json`; a traced run of the new cell reports the new metric,
+    and no file that was there is changed."""
+    chip = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(HERE, chip, ignore=shutil.ignore_patterns("__pycache__", "test_*"))
+    had = sorted(p.relative_to(chip) for p in chip.rglob("*") if p.is_file())
+    cell = tiny_cell(False)
+    serve = dict(cell.serve, pre_seconds=0.5)
+    for path, text in [("configs/tiny.json", json.dumps(cell.config)),
+                       ("traffic/tiny-chat.json", json.dumps(cell.traffic)),
+                       ("cells/tiny.chat.json", json.dumps(serve)),
+                       ("metrics/kv_pages_live.mean.py", SPAN_READER)]:
+        (chip / path).write_text(text)
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "tiny", "source": "a test's own", "file":
+                            "benchmarks/chip/configs/tiny.json", "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "tiny.chat", "config": "tiny", "traffic": "tiny-chat",
+                              "chips": 1, "why": "test"})
+    spec["per_layer"].append({"name": "kv_pages_live.mean", "unit": "pages", "better": "lower",
+                              "source": "program_counter", "layer": "kernels",
+                              "moves": "tpot_p95_ms", "workloads": ["tiny.chat"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    r = subprocess.run([sys.executable, "-c", DRIVE, str(chip), str(ROOT / "src")],
+                       cwd=tmp_path, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-4000:]
+    line = json.loads(r.stdout.strip().splitlines()[-1])
+    assert set(line["metrics"]) == {"kv_pages_live.mean"}
+    assert line["metrics"]["kv_pages_live.mean"]["value"] > 0
+    for rel in had:
+        assert (chip / rel).read_bytes() == (HERE / rel).read_bytes(), rel
+

@@ -3,6 +3,7 @@ on made-up spans, and on the records of a tiny served window."""
 from __future__ import annotations
 
 import json
+import random
 import sys
 import time
 from pathlib import Path
@@ -57,6 +58,45 @@ def test_a_gap_is_named_by_the_innermost_program_span():
         ["try_admit", pytest.approx(0.005)],
         ["step/serve.step.harvest", pytest.approx(0.005)],
     ]
+
+
+def _synthetic(seed):
+    """Device ops on two devices, the benchmark's spans one after another
+    with the driver's time between them, and program spans nested inside
+    them (some sticking out of them, some outside every one)."""
+    rng = random.Random(seed)
+    host, program, t = [], [], 0
+    for _ in range(12):
+        t += rng.randrange(MS)
+        a, t = t, t + rng.randrange(2 * MS, 20 * MS)
+        host.append((a, t, rng.choice(trace_reduce.HOST_SPANS)))
+        x, y = a - rng.randrange(MS // 10), t - rng.randrange(MS)
+        for depth in range(rng.randrange(4)):  # nested: each inside the last
+            program.append((x, y, f"serve.level{depth}"))
+            x, y = x + rng.randrange(1, (y - x) // 3), y - rng.randrange(1, (y - x) // 3)
+    program.append((t + MS, t + 2 * MS, "serve.step"))
+    ops = {}
+    for dev in ("/device:TPU:0", "/device:TPU:1"):
+        starts = sorted(rng.randrange(-2 * MS, t + 2 * MS) for _ in range(60))
+        ops[dev] = [(s, s + rng.randrange(MS), rng.choice("abc")) for s in starts]
+    return ops, host, program
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_program_spans_leave_window_busy_and_op_times_as_they_are(seed):
+    """`reduce` with and without program spans: the same window, busy time
+    and op times, exactly, and the same idle gaps, each named within the
+    benchmark span (or the driver's time) it was named by before."""
+    ops, host, program = _synthetic(seed)
+    plain = trace_reduce.reduce(ops, host, top=1000)
+    named = trace_reduce.reduce(ops, program_spans.label(host, program), top=1000)
+    for key in ("window_s", "busy_s", "op_s"):
+        assert named[key] == plain[key]
+    assert named["breakdown"]["device_ops"] == plain["breakdown"]["device_ops"]
+    gaps = named["breakdown"]["idle_gaps"]
+    assert sorted((d, n.split("/")[0]) for n, d in gaps) == sorted(
+        (d, n) for n, d in plain["breakdown"]["idle_gaps"])
+    assert any("/serve." in n for n, _ in gaps)
 
 
 def test_label_cuts_disjoint_pieces_that_cover_each_benchmark_span():

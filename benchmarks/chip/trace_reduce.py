@@ -1,8 +1,9 @@
 """Reduction of a profiler trace to device busy time, op times and idle gaps.
 
-`summarize(dir)` reads the ``.xplane.pb`` the JAX profiler wrote under
-``dir`` with `jax.profiler.ProfileData` and hands plain tuples to `reduce`,
-which does the arithmetic and is what the tests check:
+`extract(path)` reads the ``.xplane.pb`` the JAX profiler wrote (found by
+`find_xplane`) with `jax.profiler.ProfileData` and hands plain tuples to
+`reduce`, which does the arithmetic and is what the tests check
+(`program_spans.summarize` joins them, naming the gaps by program span):
 
 * the window is the span of the benchmark's host annotations (``try_admit``
   and ``step``), on the trace's own clock;
@@ -148,10 +149,6 @@ def find_xplane(trace_dir) -> str:
     if not files:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     return files[-1]
-
-
-def summarize(trace_dir) -> dict:
-    return reduce(*extract(find_xplane(trace_dir)))
 
 
 def describe(trace_dir, limit: int = 40) -> str:
