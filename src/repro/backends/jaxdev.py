@@ -193,9 +193,15 @@ class JaxComputeManager(ComputeManager):
     supported_formats = ("jax-jit", "python-callable")
     supports_suspension = False
 
-    def create_execution_unit(self, fn, *, name: str = "anonymous", jit: bool = True, static_argnums=(), **metadata) -> ExecutionUnit:
-        staged = jax.jit(fn, static_argnums=static_argnums) if jit else fn
-        return ExecutionUnit(name=name, format="jax-jit", fn=staged, metadata=metadata)
+    def create_execution_unit(self, fn, *, name: str = "anonymous", jit: bool = True,
+                              static_argnums=(), donate_argnums=(), **metadata) -> ExecutionUnit:
+        """`donate_argnums`: arguments whose buffers the unit may reuse for
+        its outputs; the caller must not touch a donated argument again."""
+        donate_argnums = tuple(donate_argnums) if jit else ()
+        staged = (jax.jit(fn, static_argnums=static_argnums, donate_argnums=donate_argnums)
+                  if jit else fn)
+        return ExecutionUnit(name=name, format="jax-jit", fn=staged,
+                             metadata={**metadata, "donate_argnums": donate_argnums})
 
     def create_processing_unit(self, resource: ComputeResource) -> ProcessingUnit:
         return ProcessingUnit(resource)

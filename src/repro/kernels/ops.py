@@ -74,16 +74,18 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=0, scale=None,
     return ref.decode_attention(q, k_cache, v_cache, pos, window=window, scale=scale)
 
 
-def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *, window=0, scale=None,
-                           impl: Optional[str] = None):
+def paged_decode_attention(q, k_pool, v_pool, page_table, pos, layer=None, *, window=0,
+                           scale=None, impl: Optional[str] = None):
+    """`layer` given: the pools are the stacked (L, P, page, KV, hd) and the
+    layer is read in place; else one layer's (P, page, KV, hd)."""
     if (impl or decode_impl()) == "pallas":
         from . import paged_decode_attention as pda
 
         return pda.paged_decode_attention(
-            q, k_pool, v_pool, page_table, pos, window=window, scale=scale
+            q, k_pool, v_pool, page_table, pos, layer, window=window, scale=scale
         )
     return ref.paged_decode_attention(
-        q, k_pool, v_pool, page_table, pos, window=window, scale=scale
+        q, k_pool, v_pool, page_table, pos, layer, window=window, scale=scale
     )
 
 

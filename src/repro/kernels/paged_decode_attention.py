@@ -15,6 +15,13 @@ the live range are never read, so padded (null page 0) or stale entries
 need no special-casing. Inside the range, positions past ``pos`` (and,
 windowed, before the window) are masked; the online-softmax state is f32.
 
+The pool is one layer's (P, page, KV, hd), or the whole stack's (L, P,
+page, KV, hd) with ``layer`` naming the layer: the layer index rides in as
+a third scalar-prefetch operand and every page copy (or the per-page
+grid's index map) reads ``pool[layer, page]``, so the decode step can
+carry the stacked pool through its layer loop and update it in place,
+never slicing a layer out.
+
 One grid step per (slot, KV head) walks the slot's live pages in blocks of
 ``ppb`` pages (about `BLOCK_BYTES` of K, set from ``page`` and ``hd``),
 gathered by its own page copies into a double-buffered VMEM block: block
@@ -80,9 +87,10 @@ def _init_state(groups: int, hd: int):
 def _walk_kernel(
     pt_ref,  # scalar prefetch: page table (B, n_pages)
     pos_ref,  # scalar prefetch: positions (B,)
+    layer_ref,  # scalar prefetch: (1,) layer of the stacked pool
     q_ref,  # (1, 1, groups, hd)
-    k_hbm,  # (P, KV, page, hd), left where it lies
-    v_hbm,  # (P, KV, page, hd)
+    k_hbm,  # (L, P, KV, page, hd), left where it lies
+    v_hbm,  # (L, P, KV, page, hd)
     o_ref,  # (1, 1, groups, hd)
     k_buf, v_buf,  # (2, ppb, page, hd): double-buffered blocks of pages
     sem,  # DMA semaphores, (k or v, buffer)
@@ -104,11 +112,13 @@ def _walk_kernel(
         """(live, copy) for each page copy of one block; a page past `last`
         is not live and is neither started nor waited for."""
         base = first + block * ppb
+        layer = layer_ref[0]
         out = []
         for i in range(ppb):
             phys = pt_ref[b, jnp.minimum(base + i, last)]
             for kv, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
-                copy = pltpu.make_async_copy(src.at[phys, h], dst.at[slot, i], sem.at[kv, slot])
+                copy = pltpu.make_async_copy(src.at[layer, phys, h], dst.at[slot, i],
+                                             sem.at[kv, slot])
                 out.append((base + i <= last, copy))
         return out
 
@@ -151,9 +161,10 @@ def _walk_kernel(
 def _page_grid_kernel(
     pt_ref,  # scalar prefetch: page table (B, n_pages)
     pos_ref,  # scalar prefetch: positions (B,)
+    layer_ref,  # scalar prefetch: (1,) layer of the stacked pool
     q_ref,  # (1, 1, groups, hd)
-    k_ref,  # (1, 1, page, hd): the index map's page, clamped to the live range
-    v_ref,  # (1, 1, page, hd)
+    k_ref,  # (1, 1, 1, page, hd): the index map's page, clamped to the live range
+    v_ref,  # (1, 1, 1, page, hd)
     o_ref,  # (1, 1, groups, hd)
     m_scr, l_scr, acc_scr,  # (groups,1),(groups,1),(groups,hd)
     *,
@@ -174,8 +185,8 @@ def _page_grid_kernel(
     @pl.when((i >= first) & (i <= last))
     def _step():
         q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0].astype(jnp.float32)
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         idx = i * page + jax.lax.broadcasted_iota(jnp.int32, (groups, page), 1)
         carry = (m_scr[...], l_scr[...], acc_scr[...])
         m_scr[...], l_scr[...], acc_scr[...] = _online_softmax_step(
@@ -193,41 +204,51 @@ def paged_decode_attention(
     v_pool,
     page_table,
     pos,
+    layer=None,
     *,
     window: int = 0,
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
 ):
-    """q: (B, H, hd); k/v_pool: (P, page, KV, hd); page_table: (B, n_pages)
-    int32 physical page per logical page; pos: scalar or (B,) last valid
-    logical slot, or negative for a slot that attends nothing (zeros out).
-    `window` > 0 masks logical slots older than ``pos - window`` and skips
-    the pages wholly before them (sliding-window layers under a
-    shared/prefix layout). Returns (B, H, hd).
+    """q: (B, H, hd); k/v_pool: (P, page, KV, hd), or (L, P, page, KV, hd)
+    with `layer` (an int32 scalar, may be traced) naming the layer read;
+    page_table: (B, n_pages) int32 physical page per logical page; pos:
+    scalar or (B,) last valid logical slot, or negative for a slot that
+    attends nothing (zeros out). `window` > 0 masks logical slots older than
+    ``pos - window`` and skips the pages wholly before them (sliding-window
+    layers under a shared/prefix layout). Returns (B, H, hd).
 
     The pages one KV head gathers are shared across its q-head group (GQA).
     """
     B, H, hd = q.shape
-    P, page, KV, _ = k_pool.shape
+    if layer is None:  # one layer's pool: a stack of one
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    _, P, page, KV, _ = k_pool.shape
+    if KV > 1:
+        # the pool's own (page, KV, hd) tiling cannot be sliced per head by a
+        # copy, so XLA relays the pool out below: relay only the layer read
+        k_pool = jax.lax.dynamic_index_in_dim(k_pool, layer, 0)
+        v_pool = jax.lax.dynamic_index_in_dim(v_pool, layer, 0)
+        layer = 0
     n = page_table.shape[1]
     groups = H // KV
     scale = scale if scale is not None else 1.0 / (hd**0.5)
     window = int(window or 0)
 
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, dtype=jnp.int32), (B,))
+    layer_arr = jnp.reshape(jnp.asarray(layer, dtype=jnp.int32), (1,))
     qg = q.reshape(B, KV, groups, hd)
-    # (P, KV, page, hd): one head's page is one (page, hd) tile. A bitcast
-    # at KV=1; at KV>1 XLA relays the pool out, since the pool's own
-    # (page, KV, hd) tiling cannot be sliced per head by a copy.
-    kt = k_pool.transpose(0, 2, 1, 3)
-    vt = v_pool.transpose(0, 2, 1, 3)
+    # (L, P, KV, page, hd): one head's page is one (page, hd) tile, a
+    # bitcast of the pool at KV=1
+    kt = k_pool.transpose(0, 1, 3, 2, 4)
+    vt = v_pool.transpose(0, 1, 3, 2, 4)
     q_spec = pl.BlockSpec((1, 1, groups, hd), lambda b, h, *_: (b, h, 0, 0))
 
     if hd % 128 == 0:
         ppb = pages_per_block(page, hd, k_pool.dtype.itemsize, n)
         kernel = functools.partial(_walk_kernel, scale=scale, page=page, ppb=ppb, window=window)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, KV),
             in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=q_spec,
@@ -238,16 +259,16 @@ def paged_decode_attention(
             ],
         )
     else:
-        def page_map(b, h, i, pt, ps):
+        def page_map(b, h, i, pt, ps, ly):
             first, last = live_pages(ps[b], page, n, window)
-            return (pt[b, jnp.clip(i, first, jnp.maximum(first, last))], h, 0, 0)
+            return (ly[0], pt[b, jnp.clip(i, first, jnp.maximum(first, last))], h, 0, 0)
 
         kernel = functools.partial(_page_grid_kernel, scale=scale, page=page, window=window)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, KV, n),
-            in_specs=[q_spec, pl.BlockSpec((1, 1, page, hd), page_map),
-                      pl.BlockSpec((1, 1, page, hd), page_map)],
+            in_specs=[q_spec, pl.BlockSpec((1, 1, 1, page, hd), page_map),
+                      pl.BlockSpec((1, 1, 1, page, hd), page_map)],
             out_specs=q_spec,
             scratch_shapes=[
                 pltpu.VMEM((groups, 1), jnp.float32),
@@ -260,5 +281,5 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, groups, hd), q.dtype),
         interpret=interpret_mode() if interpret is None else interpret,
-    )(page_table.astype(jnp.int32), pos_arr, qg, kt, vt)
+    )(page_table.astype(jnp.int32), pos_arr, layer_arr, qg, kt, vt)
     return out.reshape(B, H, hd)

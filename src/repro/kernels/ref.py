@@ -144,6 +144,7 @@ def paged_decode_attention(
     v_pool,
     page_table,
     pos,
+    layer=None,
     *,
     window: int = 0,
     scale: Optional[float] = None,
@@ -151,7 +152,8 @@ def paged_decode_attention(
     """Single-token attention over a paged (block-pool) KV cache.
 
     q: (B, H, hd); k_pool, v_pool: (P, page, KV, hd) — pages shared by every
-    sequence; page_table: (B, n_pages) int32 physical page per logical page;
+    sequence — or (L, P, page, KV, hd) with `layer` naming the layer read;
+    page_table: (B, n_pages) int32 physical page per logical page;
     pos: scalar or (B,) last valid logical slot.
 
     `window` > 0 additionally masks logical slots older than
@@ -165,6 +167,8 @@ def paged_decode_attention(
     `pos` and mask away, so no special-casing is needed. Returns (B, H, hd).
     """
     B = q.shape[0]
+    if layer is not None:
+        k_pool, v_pool = k_pool[layer], v_pool[layer]
     _, page, KV, hd = k_pool.shape
     k_eff = k_pool[page_table].reshape(B, -1, KV, hd)
     v_eff = v_pool[page_table].reshape(B, -1, KV, hd)

@@ -310,11 +310,14 @@ def paged_decode_self_attention(
     page_size: int,
     window: int = 0,
     ring: bool = True,
+    layer=None,
 ):
     """One-token decode step against a paged KV pool, natively batched.
 
     x: (B, 1, d); pool_k/v: (P, page, KV, hd) — this layer's block pool,
-    shared by all slots; table: (B, n_pages) logical->physical page map;
+    shared by all slots — or, with `layer` (may be traced), the stacked
+    (L, P, page, KV, hd) pools of a layer loop, written and read at that
+    layer in place; table: (B, n_pages) logical->physical page map;
     pos: (B,) per-slot positions; active: (B,) bool — inactive slots have
     their K/V writes routed to the null page (full layers) or clamped into
     their own ring pages, so they can never corrupt a live slot's cache.
@@ -342,15 +345,16 @@ def paged_decode_self_attention(
         # dynamic-table layers: inactive slots write the null page (their
         # table rows may reference pages since freed and reallocated)
         phys = jnp.where(active, phys, 0)
-    pool_k = pool_k.at[phys, offset].set(k[:, 0].astype(pool_k.dtype))
-    pool_v = pool_v.at[phys, offset].set(v[:, 0].astype(pool_v.dtype))
+    rows = (phys, offset) if layer is None else (layer, phys, offset)
+    pool_k = pool_k.at[rows].set(k[:, 0].astype(pool_k.dtype))
+    pool_v = pool_v.at[rows].set(v[:, 0].astype(pool_v.dtype))
 
     # ring buffers: every slot holds an in-window position once warm;
     # inactive slots pass -1, so the Pallas kernel walks none of their pages
     S_eff = table.shape[1] * page_size
     eff_pos = jnp.where(active, jnp.minimum(pos, S_eff - 1), -1)
     out = ops.paged_decode_attention(
-        q[:, 0], pool_k, pool_v, table, eff_pos, window=0 if is_ring else window
+        q[:, 0], pool_k, pool_v, table, eff_pos, layer, window=0 if is_ring else window
     )
     proj = jnp.einsum("bhk,hkd->bd", out, p["wo"].astype(x.dtype))
     return proj[:, None, :], (pool_k, pool_v)

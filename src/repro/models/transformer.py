@@ -449,11 +449,11 @@ def lm_prefix_prefill(cfg: ArchConfig, layout: PagedLayout, params, pools, row, 
 
 
 def _paged_decode_layer(cfg, layout, p_l, h, pool_kv, table, pos, active, *, window,
-                        ring=True):
+                        ring=True, layer=None):
     attn_in = rms_norm(h, p_l["ln1"], eps=cfg.norm_eps)
     attn_out, new_kv = paged_decode_self_attention(
         cfg, p_l["attn"], attn_in, pool_kv[0], pool_kv[1], table, pos, active,
-        page_size=layout.page_size, window=window, ring=ring,
+        page_size=layout.page_size, window=window, ring=ring, layer=layer,
     )
     h = h + attn_out
     ffn_in = rms_norm(h, p_l["ln2"], eps=cfg.norm_eps)
@@ -462,6 +462,28 @@ def _paged_decode_layer(cfg, layout, p_l, h, pool_kv, table, pos, active, *, win
     else:
         y = ffn(cfg, p_l["ffn"], ffn_in)
     return h + y, new_kv
+
+
+def _paged_decode_stack(cfg, layout, stack, h, pools, table, pos, active, *, window,
+                        ring=True):
+    """Decode `h` through a (L, ...) layer stack whose stacked (L, P, page,
+    KV, hd) pools ride in the layer loop's carry beside `h`: each layer
+    writes its new K/V rows into the carried pools and reads its own layer
+    of them in place, so no layer's pool is sliced out or stacked anew.
+    Returns (h, {"k", "v"})."""
+
+    def body(carry, xs):
+        h, k, v = carry
+        p_l, layer = xs
+        h, (k, v) = _paged_decode_layer(
+            cfg, layout, p_l, h, (k, v), table, pos, active,
+            window=window, ring=ring, layer=layer,
+        )
+        return (h, k, v), None
+
+    layers = jnp.arange(pools["k"].shape[0], dtype=jnp.int32)
+    (h, k, v), _ = maybe_scan(cfg, body, (h, pools["k"], pools["v"]), (stack, layers))
+    return h, {"k": k, "v": v}
 
 
 def lm_paged_decode_step(cfg: ArchConfig, layout: PagedLayout, params, pools, full_table, tokens, pos, active):
@@ -480,15 +502,9 @@ def lm_paged_decode_step(cfg: ArchConfig, layout: PagedLayout, params, pools, fu
     local_window = layout.window if (layout.ring or layout.shared) else 0
 
     if "layers" in params:
-        def body(carry, xs):
-            p_l, k, v = xs
-            new_h, (nk, nv) = _paged_decode_layer(
-                cfg, layout, p_l, carry, (k, v), full_table, pos, active, window=0
-            )
-            return new_h, (nk, nv)
-
-        h, (nk, nv) = maybe_scan(cfg, body, h, (params["layers"], pools["k"], pools["v"]))
-        new_pools = {"k": nk, "v": nv}
+        h, new_pools = _paged_decode_stack(
+            cfg, layout, params["layers"], h, pools, full_table, pos, active, window=0
+        )
     else:
         g = cfg.global_interval
 
@@ -518,18 +534,10 @@ def lm_paged_decode_step(cfg: ArchConfig, layout: PagedLayout, params, pools, fu
         h, new_unit_pools = maybe_scan(cfg, unit_body, h, (params["units"], pools["units"]))
         new_pools = {"units": new_unit_pools}
         if "tail" in params:
-            def tail_body(carry, xs):
-                p_l, k, v = xs
-                new_h, (nk, nv) = _paged_decode_layer(
-                    cfg, layout, p_l, carry, (k, v), local_table, pos, active,
-                    window=local_window, ring=layout.ring,
-                )
-                return new_h, (nk, nv)
-
-            h, (nk, nv) = maybe_scan(
-                cfg, tail_body, h, (params["tail"], pools["tail"]["k"], pools["tail"]["v"])
+            h, new_pools["tail"] = _paged_decode_stack(
+                cfg, layout, params["tail"], h, pools["tail"], local_table, pos, active,
+                window=local_window, ring=layout.ring,
             )
-            new_pools["tail"] = {"k": nk, "v": nv}
 
     h = rms_norm(h, params["final_norm"], eps=cfg.norm_eps)
     logits = unembed(params["embed"], h[:, 0], tie=cfg.tie_embeddings)

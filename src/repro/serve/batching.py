@@ -24,7 +24,7 @@ prefill inputs (VLM patches, audio frames) are out of scope here.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -164,6 +164,13 @@ class PagedSlotDecoder:
     finishes mid-interval freezes in place (its writes are routed to the
     null page) and is harvested at the next sync point, so outputs are
     token-identical to the per-tick dense path.
+
+    The pool is written in place: each tick writes only the new K/V rows.
+    `commit_and_arm` takes the pools and the control table donated, so a
+    reference passed to it is dead; `kv.pools` and `ctl` are rebound from
+    every unit's result. The fused unit leaves the pools it is given intact
+    (a caller may keep them across an interval), at one copy of the pool
+    per interval.
     """
 
     def __init__(
@@ -241,8 +248,10 @@ class PagedSlotDecoder:
             row = jnp.stack([arm[1], arm[2], jnp.int32(0), arm[3], arm[4], arm[5]])
             return pools, ctl.at[arm[0]].set(row)
 
+        # the pools and the control table are donated: the unit writes them
+        # in place, and the decoder keeps only the references it returns
         self._commit_unit = cm.create_execution_unit(
-            commit_and_arm, name="commit_and_arm", jit=True
+            commit_and_arm, name="commit_and_arm", jit=True, donate_argnums=(0, 4)
         )
 
         self._prefix_unit = None
@@ -302,6 +311,7 @@ class PagedSlotDecoder:
         self._fused_unit = cm.create_execution_unit(
             fused_ticks, name=f"fused_decode_x{K}", jit=True
         )
+        self._unit_memory: Optional[Dict[str, int]] = None
 
         # device-resident control table (host reads a summary per interval);
         # DONE=1 everywhere: free slots never decode
@@ -381,3 +391,24 @@ class PagedSlotDecoder:
         summary = np.asarray(summary)  # the interval's only device->host copy
         K = self.sync_interval
         return summary[:, :K], summary[:, K].astype(bool), summary[:, K + 1]
+
+    def stats(self) -> Dict[str, int]:
+        """What the fused unit's executable reports, read once and kept:
+        `pool_alias_bytes`, the bytes of its inputs that its outputs reuse
+        in place, and `unit_temp_bytes`, its scratch. The unit is staged for
+        the live pools and control table, as `run_interval` stages it, so
+        after an interval it is found compiled. Empty on a backend whose
+        units are not staged."""
+        fn = self._fused_unit.fn
+        if self._unit_memory is None and not hasattr(fn, "lower"):  # not staged
+            self._unit_memory = {}
+        if self._unit_memory is None:
+            table = np.zeros((self.max_slots, self.layout.n_pages_seq), np.int32)
+            with jax.default_device(self.rt.processing_unit.context):
+                lowered = fn.lower(self.params, self.kv.pools, jnp.asarray(table), self.ctl)
+                memory = lowered.compile().memory_analysis()
+            self._unit_memory = {
+                "pool_alias_bytes": int(memory.alias_size_in_bytes),
+                "unit_temp_bytes": int(memory.temp_size_in_bytes),
+            }
+        return dict(self._unit_memory)

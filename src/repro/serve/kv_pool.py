@@ -9,9 +9,12 @@ decode growth draws them, eviction frees them, all against a
 `MemorySlotPool` (core/managers.py) whose null page 0 is pinned so inactive
 slots' masked writes can never land on live data.
 
-The tensors themselves are functionally updated by the decode execution
-units (XLA rewrites buffers in place where it can); the registered slots
-record the allocation the pool handed to the compute layer — the same
+The tensors themselves are written in place: `commit_and_arm` takes them
+donated and returns them in the same buffers, `fused_decode_xK` copies
+them once per call and writes the copy, and `pools` is rebound to what
+each returns. The registered slots record the allocation the pool handed
+to the compute layer (its first buffers, which the first commit consumes)
+— the same
 allocate-once/place-many contract Specx task views and HDArray slices
 expose, with the runtime, not the kernel author, owning placement.
 """
@@ -47,7 +50,8 @@ class PagedKVPool:
             )
         self.layout = layout
         #: Per-layer block-pool tensors (the device-resident cache state;
-        #: replaced functionally by commit/decode execution units).
+        #: donated to the commit unit and rebound to what the commit and
+        #: decode execution units return).
         self.pools = model.paged_ops.init_pools(layout)
 
         leaves = jax.tree_util.tree_leaves(self.pools)

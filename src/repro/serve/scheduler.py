@@ -451,6 +451,8 @@ class ContinuousBatchingScheduler:
             if tr.enabled:
                 interval.set(**self._kv_pages())
             out_buf, done_mask, pos = self.decoder.run_interval(self._page_table)
+            if tr.enabled:  # the unit has compiled: its memory is a cache read
+                interval.set(**self.decoder.stats())
         self._pos_host[:] = pos
         self.ticks += self.decoder.sync_interval
         with tr.span("serve.step.harvest"):

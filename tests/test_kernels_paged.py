@@ -221,6 +221,37 @@ class TestBoundedWalk:
             _oracle(q, kp, vp, tbl, pos).astype(np.float32), atol=5e-2)
 
 
+class TestLayerOfAStackedPool:
+    """A stacked (L, P, page, KV, hd) pool with a `layer`: the kernel reads
+    that layer in place (the decode step's layer loop carries the stack)."""
+
+    @pytest.mark.parametrize("window", [pytest.param(0, id="unwindowed"),
+                                        pytest.param(12, id="windowed")])
+    @pytest.mark.parametrize("kv", [1, 2])
+    @pytest.mark.parametrize("hd", [pytest.param(128, id="block-walk"),
+                                    pytest.param(160, id="page-grid")])
+    def test_matches_the_oracle_on_that_layer(self, hd, kv, window):
+        """The other layers hold NaN, so a read outside the named layer
+        would show; the layer is traced, as in the layer loop."""
+        L, layer, B, n = 3, 1, 4, 12
+        q, kp, vp, tbl = _pool_case(20 + kv, B=B, H=4, KV=kv, hd=hd, page=PAGE, n_pages=n,
+                                    pool_pages=B * n + 1, dtype=jnp.float32)
+        nan = jnp.full((L,) + kp.shape, jnp.nan, kp.dtype)
+        ks, vs = nan.at[layer].set(kp), nan.at[layer].set(vp)
+        pos = jnp.asarray([0, 17, 60, n * PAGE - 1], jnp.int32)  # first page, mid, deep
+
+        def run(impl):
+            fn = jax.jit(lambda ly: ops.paged_decode_attention(q, ks, vs, tbl, pos, ly,
+                                                                window=window, impl=impl))
+            return np.asarray(fn(jnp.int32(layer)))
+
+        want = _oracle(q, kp, vp, tbl, pos, window=window)
+        np.testing.assert_array_equal(run("ref"), want)
+        got = run("pallas")
+        np.testing.assert_allclose(got, want, atol=1e-5)
+        np.testing.assert_array_equal(got, _pallas(q, kp, vp, tbl, pos, window=window))
+
+
 class TestPagedLayout:
     def test_ring_when_window_fits(self, ):
         from repro.configs import get_config

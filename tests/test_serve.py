@@ -2,6 +2,7 @@
 (slot table, mid-decode admission, eviction), and the Channels-driven
 request front door over localsim."""
 import json
+import logging
 
 import jax
 import numpy as np
@@ -327,6 +328,100 @@ class TestPagedScheduler:
         with pytest.raises(ValueError, match="no paged KV-cache path"):
             ContinuousBatchingScheduler(model, params, max_batch=2, max_len=32,
                                         kv_mode="paged")
+
+
+def _in_place_arch(name):
+    """granite-20b: one layer stack; gemma3-tail: a sliding-window unit and a
+    two-layer tail, the tail's pools carried like the stack's."""
+    if name == "granite-20b":
+        return get_config("granite-20b", reduced=True)
+    return get_config("gemma3-1b", reduced=True).replace(num_layers=8)
+
+
+class TestPagedPoolInPlace:
+    """The paged decoder writes its pool in place: `commit_and_arm` takes
+    it donated, and the fused interval writes only the new rows of the
+    pool it returns, leaving the pool it was given intact."""
+
+    @pytest.mark.parametrize("arch, prefix_cache", [
+        ("granite-20b", True), ("gemma3-tail", True), ("gemma3-tail", False)])
+    def test_donated_pool_through_admissions_intervals_evictions(self, arch, prefix_cache):
+        """Requests sharing a prefix, more of them than slots, through a pool
+        small enough to evict: tail prefills read the pool, commits and
+        intervals write it, and every output equals the dense path's (the
+        prefix cache's shared layout, or ring pages for the tail's windowed
+        layers). Each commit consumes the pool it was given; an interval
+        leaves its pool readable, and its unit reports its memory."""
+        cfg = _in_place_arch(arch)
+        model = build(cfg)
+        params, _ = model.init(jax.random.PRNGKey(2))
+        rng = np.random.default_rng(5)
+        system = rng.integers(1, cfg.vocab_size, (10,)).tolist()
+        reqs = [
+            Request(rid=f"d{i}", max_new_tokens=int(rng.integers(3, 12)),
+                    prompt=(system if i % 2 else []) + rng.integers(
+                        1, cfg.vocab_size, (int(rng.integers(3, 9)),)).tolist())
+            for i in range(8)
+        ]
+        dense = ContinuousBatchingScheduler(model, params, max_batch=3, max_len=32).serve(reqs)
+        sched = ContinuousBatchingScheduler(
+            model, params, max_batch=3, max_len=32, kv_mode="paged", page_size=4,
+            pool_pages=20, sync_interval=3, prefix_cache=prefix_cache,
+        )
+        assert sched.decoder.layout.ring == (not prefix_cache and arch == "gemma3-tail")
+        dec = sched.decoder
+        leaves = lambda: jax.tree_util.tree_leaves(dec.kv.pools)  # noqa: E731
+        backlog, results, committed, kept = list(reqs), {}, [], []
+        while len(results) < len(reqs):
+            while backlog:
+                given = leaves()
+                if not sched.try_admit(backlog[0]):
+                    break
+                backlog.pop(0)
+                committed.append(all(x.is_deleted() for x in given))
+            given = leaves()
+            for fin in sched.step():
+                results[fin.rid] = fin
+            kept.append(not any(x.is_deleted() for x in given))
+        for r in reqs:
+            assert results[r.rid].tokens == dense[r.rid].tokens, r.rid
+        assert len(committed) == len(reqs) and all(committed)
+        assert all(kept)
+        if prefix_cache:
+            stats = sched.prefix.stats()
+            assert stats["hits"] >= 1 and stats["evictions"] >= 1
+        assert set(dec.stats()) == {"pool_alias_bytes", "unit_temp_bytes"}
+        assert dec._commit_unit.metadata["donate_argnums"] == (0, 4)
+        assert dec._fused_unit.metadata["donate_argnums"] == ()
+
+    def test_stats_read_the_unit_an_interval_compiled(self, caplog):
+        """The scheduler reads `stats()` while tracing, after an interval:
+        the executable it reads is the one the interval compiled, so the
+        read compiles nothing."""
+        cfg = get_config("granite-20b", reduced=True)
+        model = build(cfg)
+        params, _ = model.init(jax.random.PRNGKey(2))
+        sched = ContinuousBatchingScheduler(
+            model, params, max_batch=2, max_len=16, kv_mode="paged", sync_interval=2)
+        sched.serve([Request(rid="s", prompt=[5, 6, 7], max_new_tokens=4)])
+        with caplog.at_level(logging.WARNING, logger="jax"), jax.log_compiles(True):
+            stats = sched.decoder.stats()
+        assert stats["unit_temp_bytes"] > 0
+        assert not [r for r in caplog.messages if "XLA compilation" in r]
+
+    def test_unjitted_backend_reports_no_unit_memory(self):
+        """On hostcpu the units run unstaged: nothing is donated, and the
+        decoder reports no executable's memory."""
+        cfg = get_config("granite-20b", reduced=True)
+        model = build(cfg)
+        params, _ = model.init(jax.random.PRNGKey(2))
+        sched = ContinuousBatchingScheduler(
+            model, params, max_batch=1, max_len=16, kv_mode="paged", sync_interval=2,
+            runtime=Runtime("hostcpu"),
+        )
+        [fin] = sched.serve([Request(rid="h", prompt=[5, 6, 7], max_new_tokens=3)]).values()
+        assert len(fin.tokens) == 3
+        assert sched.decoder.stats() == {}
 
 
 class TestPrefixCacheScheduler:
