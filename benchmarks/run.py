@@ -6,9 +6,6 @@
                                                           # verifies the scripts
                                                           # still run end-to-end
     PYTHONPATH=src python -m benchmarks.run --repeats 5   # warmup + median-of-5
-                                                          # (serve numbers swing
-                                                          # badly under load)
-    PYTHONPATH=src python -m benchmarks.run serve --kv-mode paged
 
 Paper artifact map:
     bench_channels     -> Fig. 8   (ping-pong goodput, 2 comm backends)
@@ -16,8 +13,8 @@ Paper artifact map:
     bench_tasking_fib  -> Fig. 9   (fine-grained tasking overhead)
     bench_jacobi       -> Figs. 10/11 (coarse tasking + strong/weak scaling)
     bench_rooflines    -> EXPERIMENTS.md §Roofline source table
-    bench_serve        -> BENCH_serve.json (serial vs continuous vs paged)
-Writes benchmarks/results.csv.
+Writes benchmarks/results.csv (benchmarks/results_smoke.csv with --smoke).
+The serving stack is measured on the chip by benchmarks/chip/.
 """
 from __future__ import annotations
 
@@ -34,7 +31,6 @@ from . import (
     bench_inference,
     bench_jacobi,
     bench_rooflines,
-    bench_serve,
     bench_tasking_fib,
 )
 
@@ -44,7 +40,6 @@ ALL = {
     "tasking_fib": bench_tasking_fib.run,
     "jacobi": bench_jacobi.run,
     "rooflines": bench_rooflines.run,
-    "serve": bench_serve.run,
 }
 
 
@@ -59,17 +54,9 @@ def _median_merge(rows_per_repeat: list[list[dict]]) -> list[dict]:
     return merged
 
 
-def _run_bench(fn, *, smoke: bool, repeats: int, kv_mode: str | None,
-               prefix_cache: bool = False) -> list[dict]:
-    kwargs = {}
-    accepted = inspect.signature(fn).parameters
-    if smoke:
-        kwargs["smoke"] = True
-    if kv_mode is not None and "kv_mode" in accepted:
-        kwargs["kv_mode"] = kv_mode
-    if prefix_cache and "prefix_cache" in accepted:
-        kwargs["prefix_cache"] = True
-    if repeats > 1 and "repeats" in accepted:
+def _run_bench(fn, *, smoke: bool, repeats: int) -> list[dict]:
+    kwargs = {"smoke": True} if smoke else {}
+    if repeats > 1 and "repeats" in inspect.signature(fn).parameters:
         # the bench aggregates internally (and runs its own warmup pass)
         return fn(**kwargs, repeats=repeats)
     if repeats > 1:
@@ -85,11 +72,6 @@ def main() -> None:
     ap.add_argument("--repeats", type=int, default=1,
                     help="measured repetitions per bench (plus one warmup "
                     "iteration); rows report the field-wise median")
-    ap.add_argument("--kv-mode", choices=("dense", "paged", "both"), default=None,
-                    help="KV-cache mode(s) for benches that serve (bench_serve)")
-    ap.add_argument("--prefix-cache", action="store_true",
-                    help="add the shared-prompt prefix-cache rows to "
-                    "bench_serve (paged with vs without the radix cache)")
     args = ap.parse_args()
     enable_compile_cache()
     names = args.names or list(ALL)
@@ -97,8 +79,7 @@ def main() -> None:
     for name in names:
         print(f"=== bench: {name}{' (smoke)' if args.smoke else ''} ===")
         t0 = time.monotonic()
-        rows = _run_bench(ALL[name], smoke=args.smoke, repeats=args.repeats,
-                          kv_mode=args.kv_mode, prefix_cache=args.prefix_cache)
+        rows = _run_bench(ALL[name], smoke=args.smoke, repeats=args.repeats)
         print(f"=== {name}: {len(rows)} rows in {time.monotonic() - t0:.1f}s ===\n")
         all_rows.extend(rows)
 

@@ -233,10 +233,12 @@ def _agreement(a: dict, b: dict, requests) -> tuple:
 
 
 def serve_phase(reduced: bool = False) -> dict:
-    """gemma3-1b through `serve.main`: the paged path (ring pages for the
-    window layers), then the prefix-cache path (shared layout, tail prefill,
-    copy-on-write) on the same requests. Reports how many tokens agree
-    between them, and with the serial engine on two requests."""
+    """gemma3-1b through `serve.main`: the paged path with the prefix cache
+    off (every admission a miss: the whole prompt as the tail), then with
+    it on (hits, shared pages, copy-on-write) on the same requests. Both
+    prefill through the tail prefill over one paged layout. Reports how
+    many tokens agree between them, and with the serial engine on two
+    requests."""
     requests = smoke_requests(get_config(ARCH, reduced=reduced).vocab_size)
     total = NEW_TOKENS * len(requests)
     paged = _serve("paged", _serve_argv("continuous", reduced), requests)
@@ -247,17 +249,16 @@ def serve_phase(reduced: bool = False) -> dict:
     agree, differ = _agreement(paged.results, prefix.results, requests)
     print(f"serve: paged and paged+prefix agree on {agree}/{total} tokens; "
           f"requests that differ: {differ}; prefix cache hits {hits}", flush=True)
-    # The two runs prefill differently (the whole prompt at once, or a tail
-    # against pages gathered from the pool, even on a miss) and walk the
-    # window layers' pages in different orders, so in bfloat16 a near-tie
-    # between two logits can flip a greedy token. That is rare per request;
-    # a fault in a path diverges every request that takes it: all of them
-    # for the tail prefill, the cache hits (a third) for shared pages and
-    # copy-on-write. More than a quarter diverging fails.
+    # A hit prefills only its tail, against the prefix's K/V read back from
+    # pool pages, where the cache-off run prefills the whole prompt; in
+    # bfloat16 a near-tie between two logits can then flip a greedy token.
+    # That is rare per request; a fault in shared pages or copy-on-write
+    # diverges every request that takes it, the cache hits (a third). More
+    # than a quarter diverging fails.
     check(len(differ) <= len(requests) // 4,
           f"paged and paged+prefix tokens differ for {differ}")
 
-    # two LONG prompts (the window wraps in the serial engine's ring too)
+    # two LONG prompts (past the window: the serial engine's ring wraps)
     pair = [r for r in requests if len(r.prompt) == LONG][:2]
     serial = _serve("serial", _serve_argv("serial", reduced), pair)
     serial_agree, _ = _agreement(serial.results, paged.results, pair)

@@ -216,7 +216,7 @@ def paged_decode_attention(
     scalar or (B,) last valid logical slot, or negative for a slot that
     attends nothing (zeros out). `window` > 0 masks logical slots older than
     ``pos - window`` and skips the pages wholly before them (sliding-window
-    layers under a shared/prefix layout). Returns (B, H, hd).
+    layers). Returns (B, H, hd).
 
     The pages one KV head gathers are shared across its q-head group (GQA).
     """

@@ -114,8 +114,8 @@ def _masked_decode(q, k_cache, v_cache, pos, *, window, scale):
     """Shared single-token GQA decode body: slot-validity masking
     (slot <= pos), plus an optional position-window mask (slot > pos -
     window) for dynamically-tabled sliding-window layers. One copy of the
-    scaled-dot-product/softmax/einsum oracle serves both the ring path
-    (window=0 — validity only) and the paged shared-layout path."""
+    scaled-dot-product/softmax/einsum oracle serves both the dense ring path
+    (window=0 — validity only) and the paged path."""
     B, H, hd = q.shape
     _, S, KV, _ = k_cache.shape
     groups = H // KV
@@ -157,9 +157,9 @@ def paged_decode_attention(
     pos: scalar or (B,) last valid logical slot.
 
     `window` > 0 additionally masks logical slots older than
-    ``pos - window`` — sliding-window layers under a *shared* (prefix-cache)
-    layout page every position through the dynamic table instead of a ring,
-    so the window must be enforced by position masking here.
+    ``pos - window`` — the paged layout pages every position of a
+    sliding-window layer through the table instead of a ring, so the window
+    must be enforced by position masking here.
 
     Semantics of record for the Pallas paged kernel: gather each sequence's
     pages into a dense (n_pages*page) view, then run the dense decode oracle

@@ -13,10 +13,13 @@ imports here; fleet mode assembles its localsim world inside serve/router).
         --mode fleet --workers 2 --max-batch 4 --requests 16
 
 ``--kv-mode paged`` serves from a paged KV-cache pool (block-pool tensors
-behind a scheduler-owned page table, admission bounded by free pages) with
-the device-resident decode loop (`--sync-interval` fused ticks per host
-sync). ``--kv-mode dense`` is the per-slot dense-cache baseline. Both apply
-per worker in fleet mode.
+behind a scheduler-owned page table, every layer paged alike, admission
+bounded by free pages) with the device-resident decode loop
+(`--sync-interval` fused ticks per host sync); each admission prefills the
+prompt's uncached tail against pages gathered from the pool, the whole
+prompt when nothing is cached. ``--prefix-cache`` adds the radix cache that
+finds cached prefixes. ``--kv-mode dense`` is the per-slot dense-cache
+baseline. Both apply per worker in fleet mode.
 
 `main` returns a `ServeReport`: the tokens and finish reason of every
 request, as the chosen mode produced them, so programmatic callers (the
@@ -82,8 +85,8 @@ def main(argv=None, *, requests: Optional[Sequence[Request]] = None) -> ServeRep
                     "hold a full-length sequence)")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="paged mode: refcounted radix prefix cache — shared "
-                    "prompt prefixes are forked by page reference and only "
-                    "the uncached tail is prefilled (fleet mode adds "
+                    "prompt prefixes are forked by page reference, so the "
+                    "tail prefill skips them (fleet mode adds "
                     "prefix-affinity routing)")
     ap.add_argument("--prefix-share", type=float, default=0.0,
                     help="fraction of requests opening with a shared system "

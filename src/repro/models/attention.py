@@ -112,7 +112,7 @@ def tail_prefill_attention(
 
     x: (B, S_tail, d) — the uncached tail tokens, living at absolute
     positions [offset, offset + S_tail); cache: (k, v) each (B, S_buf, KV, hd)
-    full-depth buffers (no ring — prefix sharing pages every layer densely).
+    full-depth buffers (no ring — the paged layout keeps every position).
     New K/V is written at the tail's absolute positions — overwriting from
     the divergence point on, which is what makes a copied boundary page
     copy-on-WRITE — and the tail attends over the whole cache with causal
@@ -208,50 +208,28 @@ def init_kv_cache(cfg: ArchConfig, batch: int, max_len: int, *, window: int = 0,
 class PagedLayout:
     """Static geometry of a paged KV cache.
 
-    Full-attention layers share one growing page table (``n_pages_seq``
-    logical pages per slot) over a pool of ``num_pages`` physical pages;
-    physical page 0 is the *null page* — never allocated, it absorbs the
-    masked writes of inactive slots and pads unallocated table entries.
+    Every layer shares one growing page table (``n_pages_seq`` logical
+    pages per slot) over a pool of ``num_pages`` physical pages; physical
+    page 0 is the *null page* — never allocated, it absorbs the masked
+    writes of inactive slots and pads unallocated table entries.
 
-    Sliding-window layers keep their ring buffers, but paged: each slot owns
-    a fixed set of ``w_pages`` ring pages (identity mapping, allocated for
-    the slot's lifetime), because a warm ring never grows or shrinks. When
-    ``max_len`` fits under the window the ring never wraps and those layers
-    degrade to full-attention paging (``ring`` False), exactly mirroring the
-    dense cache's ``S_buf = min(window, max_len)`` rule.
-
-    ``shared`` is the prefix-sharing mode (radix cache): rings are disabled
-    outright and sliding-window layers page exactly like full layers —
-    every position of every layer lives in dynamically-tabled pool pages,
-    so one page table row describes a whole prefix and matched prefixes can
-    be forked by reference. The window is enforced by *masking* in the
-    decode attention instead of by the ring's storage shape (the usual
-    price of prefix caching on sliding-window models: local-layer KV is
-    kept for all positions, not just the last ``window``).
+    Sliding-window layers page exactly like full layers: every position of
+    every layer lives in dynamically-tabled pool pages, so one page-table
+    row describes a whole prefix and matched prefixes can be forked by
+    reference. The decode attention enforces ``window`` by *masking* (the
+    usual price of prefix caching on sliding-window models: local-layer KV
+    is kept for all positions, not just the last ``window``).
     """
 
     max_slots: int
     page_size: int
     cache_len: int  # max_len rounded up to a page multiple
-    n_pages_seq: int  # full-layer page-table width (logical pages per slot)
-    num_pages: int  # full-pool physical pages, null page included
+    n_pages_seq: int  # page-table width (logical pages per slot)
+    num_pages: int  # physical pages, null page included
     window: int
-    ring: bool
-    w_pages: int  # ring pages per slot (0 when not ring)
-    shared: bool = False  # prefix-sharing layout: all layers full-paged
-
-    @property
-    def ring_pages_total(self) -> int:
-        return self.max_slots * self.w_pages
-
-    def ring_table(self) -> jnp.ndarray:
-        """(max_slots, w_pages) identity page table: slot s owns pages
-        [s*w_pages, (s+1)*w_pages). Static for the pool's lifetime."""
-        base = jnp.arange(self.max_slots, dtype=jnp.int32)[:, None] * self.w_pages
-        return base + jnp.arange(self.w_pages, dtype=jnp.int32)[None, :]
 
     def pages_for(self, n_positions: int) -> int:
-        """Full-table pages needed to hold `n_positions` cache positions."""
+        """Table pages needed to hold `n_positions` cache positions."""
         return -(-min(n_positions, self.cache_len) // self.page_size)
 
 
@@ -262,17 +240,9 @@ def paged_layout(
     max_len: int,
     page_size: int,
     num_pages: Optional[int] = None,
-    shared: bool = False,
 ) -> PagedLayout:
     cache_len = -(-max_len // page_size) * page_size
     n_pages_seq = cache_len // page_size
-    w = cfg.sliding_window or 0
-    ring = bool(w) and w <= cache_len and not shared
-    if ring and w % page_size != 0:
-        raise ValueError(
-            f"page_size {page_size} must divide sliding_window {w} "
-            f"(ring buffers are paged at page granularity)"
-        )
     if num_pages is None:
         # default: every slot can hold a full-length sequence (same ceiling
         # as the dense cache) + the null page
@@ -283,10 +253,7 @@ def paged_layout(
         cache_len=cache_len,
         n_pages_seq=n_pages_seq,
         num_pages=int(num_pages),
-        window=w,
-        ring=ring,
-        w_pages=(w // page_size) if ring else 0,
-        shared=shared,
+        window=cfg.sliding_window or 0,
     )
 
 
@@ -309,7 +276,6 @@ def paged_decode_self_attention(
     *,
     page_size: int,
     window: int = 0,
-    ring: bool = True,
     layer=None,
 ):
     """One-token decode step against a paged KV pool, natively batched.
@@ -319,42 +285,35 @@ def paged_decode_self_attention(
     (L, P, page, KV, hd) pools of a layer loop, written and read at that
     layer in place; table: (B, n_pages) logical->physical page map;
     pos: (B,) per-slot positions; active: (B,) bool — inactive slots have
-    their K/V writes routed to the null page (full layers) or clamped into
-    their own ring pages, so they can never corrupt a live slot's cache.
+    their K/V writes routed to the null page, so they can never corrupt a
+    live slot's cache.
 
-    `window` > 0 with ``ring`` (the default) selects ring semantics: writes
-    wrap at ``pos % window`` and validity saturates at the full ring.
-    `window` > 0 with ``ring=False`` is the prefix-sharing mode: writes go
-    straight through the dynamic table (one slot per position, like full
-    layers) and the window is enforced by *masking* logical slots older
-    than ``pos - window`` inside the attention — same attended set as the
-    ring, but positions stay addressable so prefixes can be shared.
+    Writes go straight through the table, one slot per position. `window`
+    > 0 (a sliding-window layer) masks logical slots older than
+    ``pos - window`` inside the attention: the attended set of a ring
+    buffer, with every position kept addressable so prefixes can be shared.
     Returns (out (B,1,d), (pool_k, pool_v)).
     """
     B = x.shape[0]
     positions = pos[:, None]  # (B, 1) — RoPE at each slot's own position
     q, k, v = _project_qkv(cfg, p, x, positions)  # (B,1,H,hd)/(B,1,KV,hd)
 
-    is_ring = bool(window) and ring
-    cache_pos = (pos % window) if is_ring else pos
-    cache_pos = jnp.where(active, cache_pos, 0)
+    cache_pos = jnp.where(active, pos, 0)
     page_idx = cache_pos // page_size
     offset = cache_pos % page_size
     phys = jnp.take_along_axis(table, page_idx[:, None], axis=1)[:, 0]
-    if not is_ring:
-        # dynamic-table layers: inactive slots write the null page (their
-        # table rows may reference pages since freed and reallocated)
-        phys = jnp.where(active, phys, 0)
+    # inactive slots write the null page (their table rows may reference
+    # pages since freed and reallocated)
+    phys = jnp.where(active, phys, 0)
     rows = (phys, offset) if layer is None else (layer, phys, offset)
     pool_k = pool_k.at[rows].set(k[:, 0].astype(pool_k.dtype))
     pool_v = pool_v.at[rows].set(v[:, 0].astype(pool_v.dtype))
 
-    # ring buffers: every slot holds an in-window position once warm;
     # inactive slots pass -1, so the Pallas kernel walks none of their pages
     S_eff = table.shape[1] * page_size
     eff_pos = jnp.where(active, jnp.minimum(pos, S_eff - 1), -1)
     out = ops.paged_decode_attention(
-        q[:, 0], pool_k, pool_v, table, eff_pos, layer, window=0 if is_ring else window
+        q[:, 0], pool_k, pool_v, table, eff_pos, layer, window=window
     )
     proj = jnp.einsum("bhk,hkd->bd", out, p["wo"].astype(x.dtype))
     return proj[:, None, :], (pool_k, pool_v)

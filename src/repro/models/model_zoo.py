@@ -32,21 +32,21 @@ class PagedOps:
     layout(max_slots=..., max_len=..., page_size=..., num_pages=None)
         -> PagedLayout (static cache geometry)
     init_pools(layout) -> per-layer block-pool pytree (no batch dim)
-    commit_prefill(layout, pools, dense_state, full_row, ring_row) -> pools
-        scatter one slot's B=1 dense prefill cache into its pages
+    commit_prefill(layout, pools, dense_state, full_row) -> pools
+        scatter one slot's B=1 full-depth prefill cache into its pages
     decode_step(layout, params, pools, full_table, tokens, pos, active)
         -> (logits (B,V), pools): one batched decode tick over the pool
     prefix_prefill(layout, params, pools, row, tokens, off)
         -> (logits (1,V), dense_caches): prefill only a prompt's uncached
-        tail against a shared prefix gathered from pool pages (requires a
-        `shared` layout; the prefix-cache admission path)
+        tail against a prefix gathered from pool pages (every admission;
+        on a miss `off` is 0 and `row` names only the null page)
     """
 
     layout: Callable
     init_pools: Callable
     commit_prefill: Callable
     decode_step: Callable
-    prefix_prefill: Callable = None
+    prefix_prefill: Callable
 
 
 @dataclasses.dataclass(frozen=True)

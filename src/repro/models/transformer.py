@@ -274,10 +274,8 @@ def init_paged_caches(cfg: ArchConfig, layout: PagedLayout):
     """Per-layer block-pool tensors matching the scan grouping, shared by
     every slot (no batch dim — the page table is the slot axis).
 
-    Full-attention layers pool `layout.num_pages` pages addressed by the
-    dynamic full table; sliding-window layers pool each slot's fixed ring
-    pages (identity table) — unless the window exceeds the cache, in which
-    case they page exactly like full layers (`layout.ring` False)."""
+    Every layer, sliding-window layers included, pools `layout.num_pages`
+    pages addressed by the scheduler's page table."""
     cd = dtype_of(cfg.compute_dtype)
     unit_len, n_units, n_tail = unit_structure(cfg)
     k_full, v_full = init_paged_kv_pool(cfg, layout.num_pages, layout.page_size, dtype=cd)
@@ -287,21 +285,19 @@ def init_paged_caches(cfg: ArchConfig, layout: PagedLayout):
             "k": jnp.broadcast_to(k_full[None], (L,) + k_full.shape),
             "v": jnp.broadcast_to(v_full[None], (L,) + v_full.shape),
         }
-    n_local = layout.ring_pages_total if layout.ring else layout.num_pages
-    k_loc, v_loc = init_paged_kv_pool(cfg, n_local, layout.page_size, dtype=cd)
     g = cfg.global_interval
     pools = {
         "units": {
-            "k_local": jnp.broadcast_to(k_loc[None, None], (n_units, g - 1) + k_loc.shape),
-            "v_local": jnp.broadcast_to(v_loc[None, None], (n_units, g - 1) + v_loc.shape),
+            "k_local": jnp.broadcast_to(k_full[None, None], (n_units, g - 1) + k_full.shape),
+            "v_local": jnp.broadcast_to(v_full[None, None], (n_units, g - 1) + v_full.shape),
             "k_global": jnp.broadcast_to(k_full[None], (n_units,) + k_full.shape),
             "v_global": jnp.broadcast_to(v_full[None], (n_units,) + v_full.shape),
         }
     }
     if n_tail:
         pools["tail"] = {
-            "k": jnp.broadcast_to(k_loc[None], (n_tail,) + k_loc.shape),
-            "v": jnp.broadcast_to(v_loc[None], (n_tail,) + v_loc.shape),
+            "k": jnp.broadcast_to(k_full[None], (n_tail,) + k_full.shape),
+            "v": jnp.broadcast_to(v_full[None], (n_tail,) + v_full.shape),
         }
     return pools
 
@@ -313,14 +309,12 @@ def _split_pages(cache, page_size: int):
     return c.reshape(c.shape[:-3] + (n, page_size) + c.shape[-2:])
 
 
-def commit_prefill_paged(cfg: ArchConfig, layout: PagedLayout, pools, dense_caches, full_row, ring_row):
-    """Scatter one slot's B=1 dense prefill caches into its pool pages.
+def commit_prefill_paged(cfg: ArchConfig, layout: PagedLayout, pools, dense_caches, full_row):
+    """Scatter one slot's B=1 full-depth prefill caches into its pool pages.
 
     full_row: (n_pages_seq,) physical pages, 0-padded past the allocation —
-    the padded writes land on the null page; ring_row: (w_pages,) the slot's
-    own ring pages (ignored when the layout is not ring-paged)."""
+    the padded writes land on the null page."""
     p = layout.page_size
-    local_row = ring_row if layout.ring else full_row
     if "k" in pools:
         return {
             "k": pools["k"].at[:, full_row].set(_split_pages(dense_caches["k"], p)),
@@ -329,16 +323,16 @@ def commit_prefill_paged(cfg: ArchConfig, layout: PagedLayout, pools, dense_cach
     du, pu = dense_caches["units"], pools["units"]
     new_pools = {
         "units": {
-            "k_local": pu["k_local"].at[:, :, local_row].set(_split_pages(du["k_local"], p)),
-            "v_local": pu["v_local"].at[:, :, local_row].set(_split_pages(du["v_local"], p)),
+            "k_local": pu["k_local"].at[:, :, full_row].set(_split_pages(du["k_local"], p)),
+            "v_local": pu["v_local"].at[:, :, full_row].set(_split_pages(du["v_local"], p)),
             "k_global": pu["k_global"].at[:, full_row].set(_split_pages(du["k_global"], p)),
             "v_global": pu["v_global"].at[:, full_row].set(_split_pages(du["v_global"], p)),
         }
     }
     if "tail" in pools:
         new_pools["tail"] = {
-            "k": pools["tail"]["k"].at[:, local_row].set(_split_pages(dense_caches["tail"]["k"], p)),
-            "v": pools["tail"]["v"].at[:, local_row].set(_split_pages(dense_caches["tail"]["v"], p)),
+            "k": pools["tail"]["k"].at[:, full_row].set(_split_pages(dense_caches["tail"]["k"], p)),
+            "v": pools["tail"]["v"].at[:, full_row].set(_split_pages(dense_caches["tail"]["v"], p)),
         }
     return new_pools
 
@@ -354,8 +348,8 @@ def _gather_pages(pool, row, cache_len: int):
 
 def gather_paged_caches(cfg: ArchConfig, layout: PagedLayout, pools, row):
     """Densify one slot's pool pages into full-depth B=1 caches — the read
-    half of copy-on-write (requires a `shared` layout: every layer's pages
-    are addressed by the same dynamic row)."""
+    half of copy-on-write (every layer's pages are addressed by the same
+    row)."""
     g = lambda pool: _gather_pages(pool, row, layout.cache_len)
     if "k" in pools:
         return {"k": g(pools["k"]), "v": g(pools["v"])}
@@ -386,9 +380,9 @@ def lm_prefix_prefill(cfg: ArchConfig, layout: PagedLayout, params, pools, row, 
     (including the boundary page being copy-on-write-forked), 0-padded;
     `tokens`: (1, S_tail) uncached tail tokens at absolute positions
     [off, off + S_tail). Gathers the prefix K/V out of pool pages into
-    full-depth dense caches (`shared` layout: local layers too), runs
-    transformer layers over the tail only — the FLOP savings of a prefix
-    hit — and returns (last-position logits (1, V), dense caches) ready for
+    full-depth dense caches (local layers too), runs transformer layers
+    over the tail only — the FLOP savings of a prefix hit — and returns
+    (last-position logits (1, V), dense caches) ready for
     `commit_prefill_paged`. `off` may be traced; compiles per tail length.
     """
     caches = gather_paged_caches(cfg, layout, pools, row)
@@ -449,11 +443,11 @@ def lm_prefix_prefill(cfg: ArchConfig, layout: PagedLayout, params, pools, row, 
 
 
 def _paged_decode_layer(cfg, layout, p_l, h, pool_kv, table, pos, active, *, window,
-                        ring=True, layer=None):
+                        layer=None):
     attn_in = rms_norm(h, p_l["ln1"], eps=cfg.norm_eps)
     attn_out, new_kv = paged_decode_self_attention(
         cfg, p_l["attn"], attn_in, pool_kv[0], pool_kv[1], table, pos, active,
-        page_size=layout.page_size, window=window, ring=ring, layer=layer,
+        page_size=layout.page_size, window=window, layer=layer,
     )
     h = h + attn_out
     ffn_in = rms_norm(h, p_l["ln2"], eps=cfg.norm_eps)
@@ -464,8 +458,7 @@ def _paged_decode_layer(cfg, layout, p_l, h, pool_kv, table, pos, active, *, win
     return h + y, new_kv
 
 
-def _paged_decode_stack(cfg, layout, stack, h, pools, table, pos, active, *, window,
-                        ring=True):
+def _paged_decode_stack(cfg, layout, stack, h, pools, table, pos, active, *, window):
     """Decode `h` through a (L, ...) layer stack whose stacked (L, P, page,
     KV, hd) pools ride in the layer loop's carry beside `h`: each layer
     writes its new K/V rows into the carried pools and reads its own layer
@@ -477,7 +470,7 @@ def _paged_decode_stack(cfg, layout, stack, h, pools, table, pos, active, *, win
         p_l, layer = xs
         h, (k, v) = _paged_decode_layer(
             cfg, layout, p_l, h, (k, v), table, pos, active,
-            window=window, ring=ring, layer=layer,
+            window=window, layer=layer,
         )
         return (h, k, v), None
 
@@ -495,11 +488,6 @@ def lm_paged_decode_step(cfg: ArchConfig, layout: PagedLayout, params, pools, fu
     """
     cd = dtype_of(cfg.compute_dtype)
     h = embed(params["embed"], tokens[:, None], compute_dtype=cd)  # (B,1,d)
-    ring_table = layout.ring_table() if layout.ring else None
-    local_table = ring_table if layout.ring else full_table
-    # shared (prefix-cache) layouts page local layers through the dynamic
-    # table and enforce the window by masking instead of a ring
-    local_window = layout.window if (layout.ring or layout.shared) else 0
 
     if "layers" in params:
         h, new_pools = _paged_decode_stack(
@@ -516,7 +504,7 @@ def lm_paged_decode_step(cfg: ArchConfig, layout: PagedLayout, params, pools, fu
                 p_l = jax.tree_util.tree_map(lambda x: x[i], p_unit)
                 hh, (nk, nv) = _paged_decode_layer(
                     cfg, layout, p_l, hh, (c["k_local"][i], c["v_local"][i]),
-                    local_table, pos, active, window=local_window, ring=layout.ring,
+                    full_table, pos, active, window=layout.window,
                 )
                 nk_l.append(nk)
                 nv_l.append(nv)
@@ -535,8 +523,8 @@ def lm_paged_decode_step(cfg: ArchConfig, layout: PagedLayout, params, pools, fu
         new_pools = {"units": new_unit_pools}
         if "tail" in params:
             h, new_pools["tail"] = _paged_decode_stack(
-                cfg, layout, params["tail"], h, pools["tail"], local_table, pos, active,
-                window=local_window, ring=layout.ring,
+                cfg, layout, params["tail"], h, pools["tail"], full_table, pos, active,
+                window=layout.window,
             )
 
     h = rms_norm(h, params["final_norm"], eps=cfg.norm_eps)

@@ -156,14 +156,17 @@ class PagedSlotDecoder:
 
     KV state lives in a shared block pool (one `(pages, page, KV, hd)`
     tensor per layer, allocated once through the HiCR MemoryManager); each
-    slot addresses its pages through the scheduler-owned page table. Decode
-    control state — last tokens, positions, done flags, per-slot budgets —
-    stays on device: `run_interval()` executes `sync_interval` fused
-    decode+sample ticks as ONE execution unit and transfers only the
-    per-interval token buffer and done mask back to the host. A slot that
-    finishes mid-interval freezes in place (its writes are routed to the
-    null page) and is harvested at the next sync point, so outputs are
-    token-identical to the per-tick dense path.
+    slot addresses its pages through the scheduler-owned page table. An
+    admission is `prefill_prefix` (the prompt's uncached tail against pages
+    gathered from the pool; all of it against null pages on a miss) and
+    `load` (its commit into the slot's pages). Decode control state — last
+    tokens, positions, done flags, per-slot budgets — stays on device:
+    `run_interval()` executes `sync_interval` fused decode+sample ticks as
+    ONE execution unit and transfers only the per-interval token buffer and
+    done mask back to the host. A slot that finishes mid-interval freezes
+    in place (its writes are routed to the null page) and is harvested at
+    the next sync point, so outputs are token-identical to the per-tick
+    dense path.
 
     The pool is written in place: each tick writes only the new K/V rows.
     `commit_and_arm` takes the pools and the control table donated, so a
@@ -184,7 +187,6 @@ class PagedSlotDecoder:
         pool_pages: Optional[int] = None,
         sync_interval: int = 8,
         runtime: Optional[Runtime] = None,
-        shared_prefix: bool = False,
     ):
         if model.paged_ops is None:
             raise ValueError(
@@ -193,78 +195,48 @@ class PagedSlotDecoder:
             )
         if sync_interval < 1:
             raise ValueError("sync_interval must be >= 1")
-        if shared_prefix and model.paged_ops.prefix_prefill is None:
-            raise ValueError(
-                f"model family {model.cfg.family!r} has no prefix-prefill path; "
-                "disable the prefix cache"
-            )
         self.model = model
         self.params = params
         self.max_slots = max_slots
         self.max_len = max_len
         self.sync_interval = sync_interval
-        self.shared_prefix = shared_prefix
         self.rt = runtime or Runtime("jaxdev")
         po = model.paged_ops
         self.layout = po.layout(
             max_slots=max_slots, max_len=max_len, page_size=page_size,
-            num_pages=pool_pages, shared=shared_prefix,
+            num_pages=pool_pages,
         )
         self.kv = PagedKVPool(self.rt, model, self.layout)
 
         cm = self.rt.compute_manager
         layout = self.layout
 
-        self._prefill_unit = None
-        if not shared_prefix:  # shared admissions go through _prefix_unit
-            prefill_fn = model.make_prefill(layout.cache_len)
+        def prefix_prefill(p, pools, row, tokens, off):
+            # greedy pick fused into the unit: admission transfers one
+            # int32, not a logits row, and dispatches no eager argmax op
+            logits, state = po.prefix_prefill(layout, p, pools, row, tokens, off)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), state
 
-            def paged_prefill(p, b):
-                # greedy pick fused into the unit: admission transfers one
-                # int32, not a logits row, and dispatches no eager argmax op
-                logits, state = prefill_fn(p, b)
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), state
-
-            self._prefill_unit = cm.create_execution_unit(
-                paged_prefill, name="paged_prefill", jit=True
-            )
-
-        # per-slot ring rows are static: keep them resident on device so an
-        # admission never re-uploads them
-        if layout.ring:
-            ring_rows = layout.ring_table()
-        else:
-            ring_rows = jnp.zeros((max_slots, 1), jnp.int32)
-        self._ring_rows = [ring_rows[s] for s in range(max_slots)]
+        self._prefix_unit = cm.create_execution_unit(
+            prefix_prefill, name="prefix_prefill", jit=True
+        )
 
         # control columns of the (slots, 6) device-resident table
         TOK, POS, DONE, STEPS, EOS, CAP = range(6)
 
-        def commit_and_arm(pools, state, full_row, ring_row, ctl, arm):
+        def commit_and_arm(pools, state, full_row, ctl, arm):
             """One dispatch per admission: scatter the prefilled dense cache
             into the slot's pages AND arm the slot's control row. `arm` is
             [slot, token, pos, steps_left, eos, cap] — a single int32 upload."""
-            pools = po.commit_prefill(layout, pools, state, full_row, ring_row)
+            pools = po.commit_prefill(layout, pools, state, full_row)
             row = jnp.stack([arm[1], arm[2], jnp.int32(0), arm[3], arm[4], arm[5]])
             return pools, ctl.at[arm[0]].set(row)
 
         # the pools and the control table are donated: the unit writes them
         # in place, and the decoder keeps only the references it returns
         self._commit_unit = cm.create_execution_unit(
-            commit_and_arm, name="commit_and_arm", jit=True, donate_argnums=(0, 4)
+            commit_and_arm, name="commit_and_arm", jit=True, donate_argnums=(0, 3)
         )
-
-        self._prefix_unit = None
-        if shared_prefix:
-            def prefix_prefill(p, pools, row, tokens, off):
-                # greedy pick fused, exactly like paged_prefill: one int32
-                # crosses to the host per admission
-                logits, state = po.prefix_prefill(layout, p, pools, row, tokens, off)
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), state
-
-            self._prefix_unit = cm.create_execution_unit(
-                prefix_prefill, name="prefix_prefill", jit=True
-            )
 
         K = sync_interval
 
@@ -321,26 +293,14 @@ class PagedSlotDecoder:
         self.ctl = jnp.asarray(ctl0)
 
     # -- admission ----------------------------------------------------------
-    def prefill(self, prompt: Sequence[int]):
-        """B=1 dense prefill with page-aligned cache headroom. Returns
-        (first greedy token, dense decoder state to commit into pages)."""
-        if self.shared_prefix:
-            # the dense prefill shapes ring-local caches; a shared layout
-            # commits full-depth caches — admissions must gather-prefill
-            raise RuntimeError("shared-prefix decoder: use prefill_prefix()")
-        tokens = jnp.asarray(np.asarray(prompt, dtype=np.int32)[None, :])
-        first, state = self.rt.run(self._prefill_unit, self.params, {"tokens": tokens})
-        return int(np.asarray(first)[0]), state
-
     def prefill_prefix(self, tail: Sequence[int], gather_row: np.ndarray, offset: int):
-        """Prefill only the uncached `tail` of a prompt against the shared
+        """Prefill only the uncached `tail` of a prompt against the cached
         prefix whose pages `gather_row` names (null-padded); `offset` is the
-        matched prefix length in tokens (0 on a cache miss — the whole
-        prompt is the tail). Returns (first greedy token, full-depth dense
-        state ready to commit into pages). Compiles once per tail length;
-        `offset` is traced, so match depth never recompiles."""
-        if self._prefix_unit is None:
-            raise RuntimeError("decoder was built without shared_prefix=True")
+        matched prefix length in tokens (0 on a miss or with no prefix
+        cache: the whole prompt is the tail, the row all null). Returns
+        (first greedy token, full-depth dense state ready to commit into
+        pages). Compiles once per tail length; `offset` is traced, so match
+        depth never recompiles."""
         tokens = jnp.asarray(np.asarray(tail, dtype=np.int32)[None, :])
         first, state = self.rt.run(
             self._prefix_unit, self.params, self.kv.pools,
@@ -374,8 +334,7 @@ class PagedSlotDecoder:
         )
         self.kv.pools, self.ctl = self.rt.run(
             self._commit_unit, self.kv.pools, state,
-            jnp.asarray(full_row, jnp.int32), self._ring_rows[slot],
-            self.ctl, jnp.asarray(arm),
+            jnp.asarray(full_row, jnp.int32), self.ctl, jnp.asarray(arm),
         )
 
     # -- one fused interval --------------------------------------------------

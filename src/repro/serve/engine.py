@@ -2,10 +2,10 @@
 uniform state protocol.
 
 `ServeEngine` handles one batch end-to-end at a time — it is the serial
-baseline that `serve/scheduler.py`'s continuous-batching path is measured
-against (benchmarks/bench_serve.py). Execution units are dispatched through
-a HiCR compute manager obtained from a registry-built `Runtime` facade, so
-the engine never imports a concrete backend.
+reference whose tokens `serve/scheduler.py`'s continuous-batching paths
+must reproduce exactly (tests/test_serve.py). Execution units are
+dispatched through a HiCR compute manager obtained from a registry-built
+`Runtime` facade, so the engine never imports a concrete backend.
 
 The channel front door lives in `serve/server.py` (`ChannelServer`), driven
 by the continuous-batching scheduler.

@@ -68,12 +68,10 @@ class PagedKVPool:
                         mm.register_tensor_slot(space, np.asarray(leaf))
                     )
 
-        # one logical page spans every full-layer pool: aggregate their bytes
-        full_bytes = sum(
-            leaf.nbytes for leaf in leaves if leaf.shape[-4] == layout.num_pages
-        )
+        # one logical page spans every layer's pool: aggregate their bytes
+        page_bytes = sum(leaf.nbytes for leaf in leaves) // layout.num_pages
         self.accounting = MemorySlotPool(
-            max(1, full_bytes // layout.num_pages),
+            max(1, page_bytes),
             layout.num_pages,
             backing=tuple(self.slots),
             reserved_blocks=(0,),  # null page: padding + inactive-write sink

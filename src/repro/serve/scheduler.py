@@ -16,18 +16,22 @@ Two KV-cache modes:
 * ``kv_mode="paged"`` — slots share a block-pool cache addressed through a
   scheduler-owned page table (`PagedSlotDecoder`): pages are reserved at
   admission (admission control is page availability, not a slot-count
-  proxy), drawn as a request grows, and freed at eviction. Each scheduler
-  tick runs `sync_interval` fused decode+sample ticks device-side, so
-  tokens/positions/done-flags only cross to the host at sync points.
+  proxy), drawn as a request grows, and freed at eviction. Every admission
+  prefills the prompt's uncached tail against pages gathered from the pool
+  (the whole prompt against null pages when nothing is cached). Each
+  scheduler tick runs `sync_interval` fused decode+sample ticks
+  device-side, so tokens/positions/done-flags only cross to the host at
+  sync points.
 
-With ``prefix_cache=True`` (paged mode only) admission first asks a
-refcounted `RadixCache` (serve/prefix_cache.py) for the longest cached
-prefix of the prompt: fully-matched pages are forked by reference into the
-slot's page table (worst-case reservation shrinks by the shared pages), a
-partially-matched boundary page is copy-on-write forked through the tail
-prefill's gather, and only the uncached tail runs through the model.
-Completion *returns* pages to the cache instead of freeing them; page
-pressure LRU-evicts unreferenced cache pages before refusing admission.
+``prefix_cache=True`` (paged mode only) adds the radix cache's policy:
+admission first asks a refcounted `RadixCache` (serve/prefix_cache.py) for
+the longest cached prefix of the prompt: fully-matched pages are forked by
+reference into the slot's page table (worst-case reservation shrinks by the
+shared pages), a partially-matched boundary page is copy-on-write forked
+through the tail prefill's gather, and only the uncached tail runs through
+the model. Completion *returns* pages to the cache instead of freeing them;
+page pressure LRU-evicts unreferenced cache pages before refusing
+admission. The pool's layout and the prefill are the same either way.
 
 With the Runtime's tracer on (``runtime.tracer.enable()``), admission
 records ``serve.admit`` with its phases and each step records
@@ -141,7 +145,6 @@ class ContinuousBatchingScheduler:
                 model, params, max_slots=max_batch, max_len=max_len,
                 page_size=page_size, pool_pages=pool_pages,
                 sync_interval=sync_interval, runtime=runtime,
-                shared_prefix=prefix_cache,
             )
             if prefix_cache:
                 self.prefix = RadixCache(self.decoder.kv, self.decoder.layout.page_size)
@@ -273,22 +276,20 @@ class ContinuousBatchingScheduler:
 
         try:
             with tr.span("serve.admit.prefill") as psp:
-                if self.prefix is not None:
-                    # shared-prefix decoders always admit through the gather
-                    # unit (a miss — matched or demoted — gathers null pages)
+                if self.kv_mode == "dense":
+                    psp.set(tail_len=prompt_len)
+                    first, state = self.decoder.prefill(request.prompt)
+                else:
+                    # a miss (no cache, no match, or a demoted match)
+                    # gathers null pages at offset 0
                     off = m.matched_len if m is not None else 0
-                    row = (
-                        self._gather_row(m) if m is not None
-                        else np.zeros((self.decoder.layout.n_pages_seq,), np.int32)
-                    )
-                    sp.set(matched_len=off)
+                    row = self._gather_row(m)
+                    if self.prefix is not None:
+                        sp.set(matched_len=off)
                     psp.set(tail_len=prompt_len - off)
                     first, state = self.decoder.prefill_prefix(
                         request.prompt[off:], row, off
                     )
-                else:
-                    psp.set(tail_len=prompt_len)
-                    first, state = self.decoder.prefill(request.prompt)
         except BaseException:
             if new_pages:  # a failed prefill must not strand the reservation
                 self.decoder.kv.free((), unreserve=new_pages)
@@ -350,12 +351,13 @@ class ContinuousBatchingScheduler:
         """Page-table row for the tail prefill's prefix gather: the matched
         pages (by reference) plus the copy-on-write boundary source,
         null-padded — padded gathers read the null page and sit past every
-        position the tail can attend."""
+        position the tail can attend. All null without a match `m`."""
         row = np.zeros((self.decoder.layout.n_pages_seq,), dtype=np.int32)
-        shared = m.shared_pages
-        row[: len(shared)] = shared
-        if m.boundary is not None:
-            row[len(shared)] = m.boundary.page
+        if m is not None:
+            shared = m.shared_pages
+            row[: len(shared)] = shared
+            if m.boundary is not None:
+                row[len(shared)] = m.boundary.page
         return row
 
     def _finish(self, request: Request, emitted: List[int]) -> FinishedRequest:

@@ -253,44 +253,27 @@ class TestLayerOfAStackedPool:
 
 
 class TestPagedLayout:
-    def test_ring_when_window_fits(self, ):
+    @pytest.mark.parametrize("max_len, page, slots, n_seq, probe", [
+        pytest.param(37, 16, 4, 3, (17, 2), id="window-inside-cache"),
+        pytest.param(16, 4, 2, 4, (5, 2), id="window-equals-cache"),
+        pytest.param(12, 4, 2, 3, (13, 3), id="window-beyond-cache"),
+        pytest.param(64, 12, 2, 6, (13, 2), id="page-not-dividing-window"),
+    ])
+    def test_one_layout_pages_every_layer(self, max_len, page, slots, n_seq, probe):
+        """Sliding-window layers page through the same table as full
+        layers, whatever the window's size against the cache or the page:
+        the window survives only as a mask, and every layer's pool holds
+        the layout's `num_pages` pages."""
         from repro.configs import get_config
+        from repro.models.transformer import init_paged_caches
 
         cfg = get_config("gemma3-1b", reduced=True)  # sliding_window=16
-        lay = paged_layout(cfg, max_slots=4, max_len=37, page_size=16)
-        assert (lay.cache_len, lay.n_pages_seq) == (48, 3)
-        assert lay.ring and lay.w_pages == 1 and lay.ring_pages_total == 4
-        rt = np.asarray(lay.ring_table())
-        assert rt.shape == (4, 1) and rt[:, 0].tolist() == [0, 1, 2, 3]
-        assert lay.pages_for(1) == 1 and lay.pages_for(17) == 2
-
-    def test_window_larger_than_cache_degrades_to_full(self):
-        from repro.configs import get_config
-
-        cfg = get_config("gemma3-1b", reduced=True)
-        lay = paged_layout(cfg, max_slots=2, max_len=12, page_size=4)
-        assert not lay.ring and lay.w_pages == 0  # window 16 > cache 12
-
-    def test_shared_layout_disables_ring_keeps_window(self):
-        """Prefix-sharing layouts page every layer through the dynamic
-        table: no ring even when the window fits, but the window value
-        survives for position masking."""
-        from repro.configs import get_config
-
-        cfg = get_config("gemma3-1b", reduced=True)  # sliding_window=16
-        lay = paged_layout(cfg, max_slots=4, max_len=37, page_size=16, shared=True)
-        assert lay.shared and not lay.ring and lay.w_pages == 0
+        lay = paged_layout(cfg, max_slots=slots, max_len=max_len, page_size=page)
         assert lay.window == 16
-        # and page_size no longer needs to divide the window (no ring)
-        lay2 = paged_layout(cfg, max_slots=2, max_len=24, page_size=12, shared=True)
-        assert lay2.shared and not lay2.ring
-
-    def test_page_size_must_divide_window(self):
-        from repro.configs import get_config
-
-        cfg = get_config("gemma3-1b", reduced=True)
-        with pytest.raises(ValueError, match="must divide sliding_window"):
-            paged_layout(cfg, max_slots=2, max_len=64, page_size=12)
+        assert lay.n_pages_seq == n_seq and lay.num_pages == slots * n_seq + 1
+        assert lay.pages_for(1) == 1 and lay.pages_for(probe[0]) == probe[1]
+        pools = init_paged_caches(cfg, lay)
+        assert {leaf.shape[-4] for leaf in jax.tree_util.tree_leaves(pools)} == {lay.num_pages}
 
 
 class TestMemorySlotPool:
@@ -349,7 +332,7 @@ class TestPagedModelStepParity:
         )
         row = np.zeros((layout.n_pages_seq,), np.int32)
         row[: layout.pages_for(len(prompt) + 1)] = [1, 2][: layout.pages_for(len(prompt) + 1)]
-        pools = po.commit_prefill(layout, pools, state, jnp.asarray(row), jnp.zeros((1,), jnp.int32))
+        pools = po.commit_prefill(layout, pools, state, jnp.asarray(row))
         table = jnp.asarray(np.stack([row, np.zeros_like(row)]))
         paged_logits, _ = po.decode_step(
             layout, params, pools, table,

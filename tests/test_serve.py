@@ -235,6 +235,26 @@ class TestPagedScheduler:
         assert results["e"].finish_reason == "eos"
         assert results["e"].tokens == chain[: stop + 1]
 
+    def test_window_model_with_a_page_not_dividing_its_window(self, bundle, engine):
+        """gemma3's window layers (window 16) page through the same table as
+        its global layers, so a page of 12 positions serves them with the
+        cache off; prompts and outputs run past the window and across page
+        boundaries, and the tokens equal the serial engine's ring buffers."""
+        cfg, model, params = bundle
+        sched = ContinuousBatchingScheduler(
+            model, params, max_batch=3, max_len=64,
+            kv_mode="paged", page_size=12, sync_interval=4,
+        )
+        reqs = _workload(cfg, 5, seed=3, lo_p=8, hi_p=20, lo_s=6, hi_s=16)
+        assert max(len(r.prompt) + r.max_new_tokens for r in reqs) > cfg.sliding_window + 12
+        results = sched.serve(reqs)
+        for r in reqs:
+            serial = engine.generate(
+                np.asarray([r.prompt], dtype=np.int32), steps=r.max_new_tokens
+            ).tokens[0].tolist()
+            assert results[r.rid].tokens == serial, r.rid
+        assert sched.decoder.kv.pages_used == 0
+
     def test_page_availability_admission_control(self, bundle):
         """Admission is bounded by free pool pages, not just free slots: a
         pool sized for one request backpressures the second until eviction
@@ -347,11 +367,11 @@ class TestPagedPoolInPlace:
         ("granite-20b", True), ("gemma3-tail", True), ("gemma3-tail", False)])
     def test_donated_pool_through_admissions_intervals_evictions(self, arch, prefix_cache):
         """Requests sharing a prefix, more of them than slots, through a pool
-        small enough to evict: tail prefills read the pool, commits and
-        intervals write it, and every output equals the dense path's (the
-        prefix cache's shared layout, or ring pages for the tail's windowed
-        layers). Each commit consumes the pool it was given; an interval
-        leaves its pool readable, and its unit reports its memory."""
+        small enough to evict: tail prefills read the pool (with the cache
+        off, every one a miss against null pages), commits and intervals
+        write it, and every output equals the dense path's. Each commit
+        consumes the pool it was given; an interval leaves its pool
+        readable, and its unit reports its memory."""
         cfg = _in_place_arch(arch)
         model = build(cfg)
         params, _ = model.init(jax.random.PRNGKey(2))
@@ -368,8 +388,9 @@ class TestPagedPoolInPlace:
             model, params, max_batch=3, max_len=32, kv_mode="paged", page_size=4,
             pool_pages=20, sync_interval=3, prefix_cache=prefix_cache,
         )
-        assert sched.decoder.layout.ring == (not prefix_cache and arch == "gemma3-tail")
         dec = sched.decoder
+        pages = {x.shape[-4] for x in jax.tree_util.tree_leaves(dec.kv.pools)}
+        assert pages == {dec.layout.num_pages}  # window layers included
         leaves = lambda: jax.tree_util.tree_leaves(dec.kv.pools)  # noqa: E731
         backlog, results, committed, kept = list(reqs), {}, [], []
         while len(results) < len(reqs):
@@ -391,7 +412,7 @@ class TestPagedPoolInPlace:
             stats = sched.prefix.stats()
             assert stats["hits"] >= 1 and stats["evictions"] >= 1
         assert set(dec.stats()) == {"pool_alias_bytes", "unit_temp_bytes"}
-        assert dec._commit_unit.metadata["donate_argnums"] == (0, 4)
+        assert dec._commit_unit.metadata["donate_argnums"] == (0, 3)
         assert dec._fused_unit.metadata["donate_argnums"] == ()
 
     def test_stats_read_the_unit_an_interval_compiled(self, caplog):
